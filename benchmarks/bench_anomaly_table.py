@@ -15,6 +15,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax  # noqa: E402
 
 from repro.configs.base import SHAPES, get_config, list_archs
+from repro.launch import compile_cache
 from repro.core.catalog import render_markdown, save_catalog
 from repro.core.engine import Engine
 from repro.core.sa import campaign, rank_counters
@@ -32,6 +33,7 @@ PERF = [("perf.roofline_efficiency", "min"),
 
 
 def main():
+    compile_cache.enable()
     t0 = time.time()
     archs = {a: get_config(a) for a in list_archs()}
     space = SearchSpace(archs, dict(SHAPES),

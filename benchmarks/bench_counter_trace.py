@@ -9,6 +9,7 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.core.benchscale import BENCH_SHAPES, bench_archs, bench_meshes
+from repro.launch import compile_cache
 from repro.core.engine import Engine
 from repro.core.random_search import random_search
 from repro.core.sa import simulated_annealing
@@ -31,6 +32,7 @@ def trace(result):
 
 
 def main():
+    compile_cache.enable()
     t0 = time.time()
     space = SearchSpace(bench_archs(["qwen2-1.5b", "mixtral-8x7b"]),
                         BENCH_SHAPES,

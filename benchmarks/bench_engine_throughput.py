@@ -38,6 +38,7 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.core.benchscale import BENCH_SHAPES, bench_archs, bench_meshes
+from repro.launch import compile_cache
 from repro.core.engine import Engine
 from repro.core.measure_cache import MeasureCache
 from repro.core.searchspace import SearchSpace
@@ -199,6 +200,7 @@ def run_struct(space, meshes, batches, struct_dedup, cache_path):
 
 
 def main():
+    compile_cache.enable()
     space = SearchSpace(bench_archs(["qwen2-1.5b", "mixtral-8x7b"]),
                         BENCH_SHAPES,
                         restrict={"grad_compress": ("none",),

@@ -27,6 +27,7 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.core.benchscale import BENCH_SHAPES, bench_archs, bench_meshes
+from repro.launch import compile_cache
 from repro.core.catalog import render_markdown, save_catalog
 from repro.core.corpus import Corpus
 from repro.core.engine import Engine
@@ -100,6 +101,7 @@ def run_metrics(result, gt, engine_stats):
 
 
 def main():
+    compile_cache.enable()
     t0 = time.time()
     restrict = {"grad_compress": ("none",), "scan_layers": (True,)}
     if SMOKE:

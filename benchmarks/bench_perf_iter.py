@@ -18,7 +18,9 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.configs.base import SHAPES, get_config
+from repro.launch import compile_cache
 from repro.core.counters import measure_cell
+from repro.hw import V5E
 from repro.launch.dryrun import default_policy
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import build_cell
@@ -43,13 +45,14 @@ SMOKE = bool(int(os.environ.get("SMOKE", "0")))
 
 
 def smoke_main():
+    compile_cache.enable()
     from repro.core.benchscale import BENCH_SHAPES, bench_config, bench_meshes
     t0 = time.time()
     cfg = bench_config("qwen2-1.5b")
     shape = BENCH_SHAPES["train_s"]
     mesh = bench_meshes()["single"]
     pol = default_policy(cfg, shape, n_microbatch=1)
-    m = measure_cell(build_cell(cfg, shape, pol, mesh))
+    m = measure_cell(build_cell(cfg, shape, pol, mesh), V5E)
     r = m.roofline
     print(f"bench_perf_iter,smoke,bound_ms={r['bound_s']*1e3:.1f},"
           f"dominant={r['dominant']}", flush=True)
@@ -59,6 +62,7 @@ def smoke_main():
 
 
 def main():
+    compile_cache.enable()
     t0 = time.time()
     rows = []
     for arch, shape_name, multi, overrides in CELLS:
@@ -66,7 +70,7 @@ def main():
         shape = SHAPES[shape_name]
         mesh = make_production_mesh(multi_pod=multi)
         pol = default_policy(cfg, shape, **overrides)
-        m = measure_cell(build_cell(cfg, shape, pol, mesh))
+        m = measure_cell(build_cell(cfg, shape, pol, mesh), V5E)
         r = m.roofline
         key = (arch, shape_name, "multi" if multi else "single")
         base = RECORDED_BASELINE_MS[key]

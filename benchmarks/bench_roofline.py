@@ -15,7 +15,8 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.configs.base import SHAPES, get_config, list_archs  # noqa: E402
-from repro.launch import dryrun  # noqa: E402  (sets XLA_FLAGS=512 first)
+from repro.launch import compile_cache
+from repro.launch import dryrun  # noqa: E402
 
 from common import save_json  # noqa: E402
 
@@ -83,6 +84,7 @@ def render(rows) -> str:
 
 
 def main():
+    compile_cache.enable()
     t0 = time.time()
     rows = run_all()
     md = render(rows)

@@ -17,6 +17,7 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.core import anomaly
+from repro.launch import compile_cache
 from repro.core.benchscale import BENCH_SHAPES, bench_archs, bench_meshes
 from repro.core.bo import bo_search
 from repro.core.catalog import render_markdown, save_catalog
@@ -78,6 +79,7 @@ def aggregate_stats():
 
 
 def main():
+    compile_cache.enable()
     t0 = time.time()
     space = SearchSpace(bench_archs(ARCH_SUBSET), BENCH_SHAPES,
                     restrict={"grad_compress": ("none",),

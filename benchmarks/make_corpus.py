@@ -27,6 +27,7 @@ import time
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 from repro.core import anomaly as anomaly_mod
+from repro.launch import compile_cache
 from repro.core.catalog import load_catalog
 from repro.core.corpus import Corpus, CorpusEntry, signature
 from repro.core.engine import Engine
@@ -60,6 +61,7 @@ else:
 
 
 def main():
+    compile_cache.enable()
     t0 = time.time()
     import json
     with open(CATALOG) as f:

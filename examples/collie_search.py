@@ -20,6 +20,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import argparse
 
 from repro.core.benchscale import BENCH_SHAPES, bench_archs, bench_meshes
+from repro.launch import compile_cache
 from repro.core.catalog import render_markdown
 from repro.core.engine import Engine
 from repro.core.sa import campaign, rank_counters
@@ -27,6 +28,7 @@ from repro.core.searchspace import SearchSpace
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--budget", type=int, default=60)
     ap.add_argument("--restrict", action="store_true", default=True,

@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import RunPolicy, ShapeSpec
+from repro.launch import compile_cache
 from repro.configs.all_archs import smoke_config
 from repro.ckpt.checkpoint import CheckpointManager
 from repro.data.pipeline import SyntheticLM
@@ -30,6 +31,7 @@ class SimClock:
 
 
 def main():
+    compile_cache.enable()
     cfg = smoke_config("tinyllama-1.1b")
     shape = ShapeSpec("el", "train", 64, 8)
     policy = RunPolicy(remat="none", dtype="f32")

@@ -11,6 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import RunPolicy, ShapeSpec
+from repro.launch import compile_cache
 from repro.configs.all_archs import smoke_config
 from repro.data.pipeline import SyntheticLM
 from repro.models import api
@@ -20,6 +21,7 @@ from repro.train.train_step import make_init_opt, make_train_step
 
 
 def main():
+    compile_cache.enable()
     cfg = smoke_config("qwen2-1.5b")
     shape = ShapeSpec("quick", "train", 64, 8)
     policy = RunPolicy(remat="none", dtype="f32", n_microbatch=2)

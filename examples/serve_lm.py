@@ -12,12 +12,14 @@ import jax
 import numpy as np
 
 from repro.configs.base import RunPolicy
+from repro.launch import compile_cache
 from repro.configs.all_archs import smoke_config
 from repro.models import api
 from repro.serve.engine import Request, ServingEngine
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="tinyllama-1.1b")
     ap.add_argument("--requests", type=int, default=12)

@@ -20,6 +20,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig, RunPolicy, ShapeSpec
+from repro.launch import compile_cache
 from repro.ckpt.checkpoint import CheckpointManager
 from repro.data.pipeline import Prefetcher, SyntheticLM
 from repro.models import api
@@ -38,6 +39,7 @@ PRESETS = {
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--preset", default="20m", choices=list(PRESETS))
     ap.add_argument("--steps", type=int, default=200)

@@ -177,9 +177,7 @@ def activation_bytes_floor(cfg, shape, policy, mesh) -> float:
     return per_tok * tokens_dev * passes
 
 
-def step_floor_seconds(cfg, shape, policy, mesh, chip=None) -> dict:
-    from .. import hw
-    chip = chip or hw.V5E
+def step_floor_seconds(cfg, shape, policy, mesh, chip) -> dict:
     n = mesh.size
     fl = total_model_flops(cfg, shape)
     # unavoidable HBM traffic: read params once (+opt r/w for train) + states

@@ -50,6 +50,7 @@ class Measurement:
     floors: dict
     perf: dict          # performance counters (lower = worse)
     diag: dict          # diagnostic counters (higher = more stressed)
+    tpu_custom_calls: int = 0   # Pallas kernel call sites Mosaic compiled
 
     def summary(self) -> dict:
         return {
@@ -105,7 +106,7 @@ def _floors_of(cell, chip: hw.ChipSpec):
     return floors, mf_useful
 
 
-def lower_cell(cell, chip: hw.ChipSpec = hw.V5E) -> LoweredCell:
+def lower_cell(cell, chip: hw.ChipSpec) -> LoweredCell:
     """Trace + lower the cell (no XLA) and fingerprint its structure."""
     t0 = time.time()
     lowered = cell.lower()
@@ -124,7 +125,7 @@ def lower_cell(cell, chip: hw.ChipSpec = hw.V5E) -> LoweredCell:
                        h.hexdigest()[:24])
 
 
-def lowered_counters(lc: LoweredCell, chip: hw.ChipSpec = hw.V5E) -> dict:
+def lowered_counters(lc: LoweredCell, chip: hw.ChipSpec) -> dict:
     """Fidelity-1 structural counters from the pre-XLA module (no compile).
 
     The lowered module is un-partitioned (it computes the *global* program;
@@ -153,8 +154,7 @@ def lowered_counters(lc: LoweredCell, chip: hw.ChipSpec = hw.V5E) -> dict:
 
 # ---------------------------------------------------------- compile phase
 
-def compile_lowered(lc: LoweredCell, chip: hw.ChipSpec = hw.V5E
-                    ) -> Measurement:
+def compile_lowered(lc: LoweredCell, chip: hw.ChipSpec) -> Measurement:
     cell = lc.cell
     t0 = time.time()
     compiled = lc.lowered.compile()
@@ -172,12 +172,10 @@ def compile_lowered(lc: LoweredCell, chip: hw.ChipSpec = hw.V5E
         "peak_bytes": (ma.argument_size_in_bytes + ma.temp_size_in_bytes
                        + ma.output_size_in_bytes - ma.alias_size_in_bytes),
     }
-    try:
-        ca = dict(compiled.cost_analysis())
-        ca = {k: ca[k] for k in ("flops", "bytes accessed") if k in ca}
-    except Exception:
-        ca = {}
-    hlo = hloanalysis.analyze(compiled.as_text())
+    ca = compiled.cost_analysis() or {}   # None where the backend has none
+    ca = {k: ca[k] for k in ("flops", "bytes accessed") if k in ca}
+    text = compiled.as_text()
+    hlo = hloanalysis.analyze(text)
 
     n = cell.mesh.size
     # per-device quantities straight from the partitioned module
@@ -229,9 +227,10 @@ def compile_lowered(lc: LoweredCell, chip: hw.ChipSpec = hw.V5E
         "n_permute": hlo["collective_count"].get("collective-permute", 0),
     }
     return Measurement(cell, compile_s, memory, ca, hlo, roofline, floors,
-                       perf, diag)
+                       perf, diag,
+                       text.count('custom_call_target="tpu_custom_call"'))
 
 
-def measure_cell(cell, chip: hw.ChipSpec = hw.V5E) -> Measurement:
+def measure_cell(cell, chip: hw.ChipSpec) -> Measurement:
     """One-shot lower + compile + analyze (the pre-split entry point)."""
     return compile_lowered(lower_cell(cell, chip), chip)

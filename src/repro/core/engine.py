@@ -3,7 +3,8 @@
 Translates a search-space point into a concrete compiled workload on the
 production mesh and returns its counters.  Compilation failures / invalid
 settings are reported as None (the search skips them), mirroring the paper's
-engine rejecting unsatisfiable verb combinations.
+engine rejecting unsatisfiable verb combinations; each failure's message is
+kept in ``Engine.failures``.
 
 Throughput layers (this is the search hot path — see ISSUE 1/2):
 
@@ -69,6 +70,7 @@ import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any
 
+from .. import hw
 from ..train.optimizer import OptConfig
 from ..launch.steps import build_cell
 from . import counters as counters_mod
@@ -128,6 +130,7 @@ class Engine:
         """
         self.space = space
         self.meshes = meshes
+        self.chip = hw.chip_of_meshes(meshes)
         self.cache = {} if cache else None
         self.verbose = verbose
         if n_workers is None:
@@ -157,7 +160,7 @@ class Engine:
                     f"COLLIE_PRESCREEN must be an integer, got {raw!r}")
         self.prescreen = max(int(prescreen), 0)
         if surrogate is None:
-            surrogate = Surrogate(space, meshes)
+            surrogate = Surrogate(space, meshes, self.chip)
         self.surrogate = surrogate or None
         self._calib_path = self._resolve_calib_path(calibrator_path)
         if self.surrogate is not None and self._calib_path:
@@ -179,6 +182,7 @@ class Engine:
         self.n_attempts = 0        # budget: unique points requested
         self.n_compiles = 0        # successful compiles
         self.n_failures = 0        # failed compile attempts
+        self.failures: list = []   # their messages, in order
         self.n_cache_hits = 0      # in-memory / in-flight hits (incl. repeats)
         self.n_disk_hits = 0       # persistent-cache hits
         self.n_cache_misses = 0    # requests that had to compile
@@ -327,8 +331,8 @@ class Engine:
                     t0 = time.time()
                     cell = build_cell(cfg, shape, policy, mesh,
                                       OptConfig(name=policy.optimizer))
-                    lc = counters_mod.lower_cell(cell)
-                    raw = counters_mod.lowered_counters(lc)
+                    lc = counters_mod.lower_cell(cell, self.chip)
+                    raw = counters_mod.lowered_counters(lc, self.chip)
                     with self._lock:
                         self.n_lowerings += 1
                         self.lower_time += time.time() - t0
@@ -582,15 +586,12 @@ class Engine:
             t0 = time.time()
             cell = build_cell(cfg, shape, policy, mesh,
                               OptConfig(name=policy.optimizer))
-            lc = counters_mod.lower_cell(cell)
+            lc = counters_mod.lower_cell(cell, self.chip)
             with self._lock:
                 self.n_lowerings += 1
                 self.lower_time += time.time() - t0
         except Exception as e:              # sharding/trace failure
-            with self._lock:
-                self.n_failures += 1
-            if self.verbose:
-                print(f"[engine] lowering failed: {e}")
+            self._note_failure("lowering", e)
             return None, None
         fp = lc.fingerprint
         key = self.space.point_key(point)
@@ -658,7 +659,7 @@ class Engine:
         """Phase 2: XLA compile + analysis of a lowered cell."""
         try:
             t0 = time.time()
-            m = counters_mod.compile_lowered(lc)
+            m = counters_mod.compile_lowered(lc, self.chip)
             with self._lock:
                 self.n_compiles += 1
                 self.compile_time += time.time() - t0
@@ -666,11 +667,19 @@ class Engine:
                       **{f"diag.{k}": v for k, v in m.diag.items()}}
             return result, m
         except Exception as e:              # compile failure
-            with self._lock:
-                self.n_failures += 1
-            if self.verbose:
-                print(f"[engine] compile failed: {e}")
+            self._note_failure("compile", e)
             return None, None
+
+    def _note_failure(self, phase, exc):
+        """Count a failed point as infeasible, and keep why: a compiler
+        refusal or a runtime fault reads like a point that does not fit
+        unless the caller looks at ``failures``."""
+        msg = f"{phase} failed: {type(exc).__name__}: {exc}"
+        with self._lock:
+            self.n_failures += 1
+            self.failures.append(msg)
+        if self.verbose:
+            print(f"[engine] {msg}")
 
     # --------------------------------------------------------------- stats
     def stats(self) -> dict:

@@ -165,6 +165,9 @@ class SearchSpace:
             optimizer=p["optimizer"],
             grad_compress=p["grad_compress"] if shape.kind == "train" else "none",
             capacity_factor=p["capacity_factor"],
+            # not a searched factor: a caller that measures the kernel path
+            # on the chip sets it on its points
+            use_pallas=bool(p.get("use_pallas", False)),
         )
         return cfg, shape, policy, p["mesh"]
 

@@ -198,7 +198,7 @@ class Calibrator:
 class Surrogate:
     """Point -> estimated flat counter dict, no compile (fidelity 0)."""
 
-    def __init__(self, space, meshes: dict, chip: hw.ChipSpec = hw.V5E,
+    def __init__(self, space, meshes: dict, chip: hw.ChipSpec,
                  calibrator: Calibrator | None = None):
         self.space = space
         self.descs = mesh_descs(meshes)

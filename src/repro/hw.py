@@ -1,7 +1,10 @@
-"""Target-hardware model (TPU v5e) used by the roofline and the anomaly monitor.
+"""Chip peaks used by the roofline and the anomaly monitor.
 
-This container is CPU-only; these constants describe the TARGET chip, per the
-assignment:  197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+One table, keyed by the ``device_kind`` JAX reports for a device.  A kind
+that is not in the table is an error, never a default.  The search runs on
+host CPU devices (virtual meshes) and on the TPU compiler's described
+chips; the counters it derives there model the chip named by
+``MODELLED_KIND``.
 """
 from __future__ import annotations
 
@@ -10,25 +13,48 @@ import dataclasses
 
 @dataclasses.dataclass(frozen=True)
 class ChipSpec:
-    name: str = "tpu-v5e"
-    peak_flops_bf16: float = 197e12  # FLOP/s per chip
-    hbm_bw: float = 819e9            # bytes/s per chip
-    ici_bw: float = 50e9             # bytes/s per link (charged per chip, conservative)
-    hbm_bytes: float = 16 * 1024**3  # HBM capacity per chip
-    vmem_bytes: float = 128 * 1024**2
+    name: str
+    peak_flops_bf16: float  # FLOP/s per chip
+    hbm_bw: float           # bytes/s per chip
+    ici_bw: float           # bytes/s per link (charged per chip, conservative)
+    hbm_bytes: float        # HBM capacity per chip
+    vmem_bytes: float
 
 
-V5E = ChipSpec()
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s ICI per chip (charged here as ~50 GB/s per link).
+CHIPS = {
+    "TPU v5 lite": ChipSpec(name="tpu-v5e", peak_flops_bf16=197e12,
+                            hbm_bw=819e9, ici_bw=50e9,
+                            hbm_bytes=16 * 1024**3, vmem_bytes=128 * 1024**2),
+}
+
+# the chip whose peaks the search's counters model when they are not
+# computed on a TPU device (CPU meshes, described topologies)
+MODELLED_KIND = "TPU v5 lite"
 
 
-def roofline_terms(flops: float, bytes_hbm: float, bytes_coll: float,
-                   n_chips: int, chip: ChipSpec = V5E) -> dict:
-    """Three-term roofline (seconds) per the assignment formulas."""
-    compute_s = flops / (n_chips * chip.peak_flops_bf16)
-    memory_s = bytes_hbm / (n_chips * chip.hbm_bw)
-    coll_s = bytes_coll / (n_chips * chip.ici_bw)
-    terms = {"compute_s": compute_s, "memory_s": memory_s, "collective_s": coll_s}
-    dom = max(terms, key=terms.get)
-    terms["dominant"] = dom
-    terms["bound_s"] = terms[dom]
-    return terms
+def chip_spec(device_kind: str) -> ChipSpec:
+    """Peaks of the chip JAX calls ``device_kind``; unknown kinds raise."""
+    try:
+        return CHIPS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no peaks recorded for device kind {device_kind!r}; "
+            f"known kinds: {sorted(CHIPS)}") from None
+
+
+V5E = chip_spec(MODELLED_KIND)
+
+
+def chip_of_meshes(meshes: dict) -> ChipSpec:
+    """The chip counters are modelled on: the TPU the meshes are made of,
+    or, for host CPU meshes and stand-ins, ``MODELLED_KIND``."""
+    from jax.sharding import Mesh
+    for mesh in meshes.values():
+        if isinstance(mesh, Mesh):
+            dev = mesh.devices.flat[0]
+            if dev.platform == "tpu":
+                return chip_spec(dev.device_kind)
+    return V5E
+

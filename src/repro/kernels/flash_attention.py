@@ -12,7 +12,8 @@ TPU adaptation notes (vs the CUDA flash-attention algorithm):
 * fully-masked causal blocks are predicated off with ``pl.when`` rather than
   skipped via grid surgery.
 
-Validated in interpret mode against ``ref.flash_attention_ref``.
+Validated against ``ref.flash_attention_ref``, in interpret mode on the CPU
+and compiled by Mosaic on the chip.
 """
 from __future__ import annotations
 
@@ -25,6 +26,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -2.3819763e38
+
+
+def _mm(a, b, contract):
+    """Contract ``a`` with ``b`` on the MXU in their own dtype, accumulating
+    in f32.  f32 operands ask for full precision: left to Mosaic's default,
+    a pass may round them to bf16."""
+    prec = jax.lax.Precision.HIGHEST if a.dtype == jnp.float32 else None
+    return jax.lax.dot_general(a, b, (contract, ((), ())), precision=prec,
+                               preferred_element_type=jnp.float32)
 
 
 # ------------------------------------------------------------------- forward
@@ -57,27 +67,23 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
 
     @pl.when(block_live)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
-        s = s * (1.0 / np.sqrt(q.shape[-1]))
+        q, k, v = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0]
+        s = _mm(q, k, ((1,), (1,))) * (1.0 / np.sqrt(q.shape[-1]))
         s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                                 # (bq, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + _mm(p.astype(v.dtype), v,
+                                                 ((1,), (0,)))
         m_ref[...] = m_new
 
     @pl.when(ki == n_kv_blocks - 1)
     def _finish():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = (m_ref[...] + jnp.log(l)).astype(jnp.float32)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        lse_ref[0, 0] = m_ref[...] + jnp.log(l)
 
 
 def flash_attention_fwd(q, k, v, *, window=None, causal_shift=0,
@@ -106,20 +112,20 @@ def flash_attention_fwd(q, k, v, *, window=None, causal_shift=0,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, qi, ki: (b, h, qi)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, qi, ki: (b, h, qi, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, nq * bq, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, nq * bq), jnp.float32),
+            jax.ShapeDtypeStruct((B, H, nq * bq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, D), jnp.float32),   # acc
-            pltpu.VMEM((bq,), jnp.float32),     # m
-            pltpu.VMEM((bq,), jnp.float32),     # l
+            pltpu.VMEM((bq, 1), jnp.float32),   # m
+            pltpu.VMEM((bq, 1), jnp.float32),   # l
         ],
         interpret=interpret,
     )(qp, kp, vp)
-    return o[:, :, :Sq], lse[:, :, :Sq]
+    return o[:, :, :Sq], lse[:, :, :Sq, 0]
 
 
 # ------------------------------------------------------------------ backward
@@ -147,22 +153,16 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
     @pl.when(block_live)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
+        q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
         lse = lse_ref[0, 0]
         delta = delta_ref[0, 0]
         scale = 1.0 / np.sqrt(q.shape[-1])
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _mm(q, k, ((1,), (1,))) * scale
         s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
-        dq_acc[...] += jax.lax.dot_general(ds, k, (((1,), (0,)), ((), ())),
-                                           preferred_element_type=jnp.float32)
+        p = jnp.exp(s - lse)
+        dp = _mm(do, v, ((1,), (1,)))
+        ds = p * (dp - delta) * scale
+        dq_acc[...] += _mm(ds.astype(k.dtype), k, ((1,), (0,)))
 
     @pl.when(ki == n_kv_blocks - 1)
     def _finish():
@@ -195,24 +195,17 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
     @pl.when(block_live)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)
-        k = k_ref[0, 0].astype(jnp.float32)
-        v = v_ref[0, 0].astype(jnp.float32)
-        do = do_ref[0, 0].astype(jnp.float32)
+        q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
         lse = lse_ref[0, 0]
         delta = delta_ref[0, 0]
         scale = 1.0 / np.sqrt(q.shape[-1])
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
+        s = _mm(q, k, ((1,), (1,))) * scale
         s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse[:, None])                       # (bq, bk)
-        dv_acc[...] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
-                                           preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale              # (bq, bk)
-        dk_acc[...] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
-                                           preferred_element_type=jnp.float32)
+        p = jnp.exp(s - lse)                                # (bq, bk)
+        dv_acc[...] += _mm(p.astype(do.dtype), do, ((0,), (0,)))
+        dp = _mm(do, v, ((1,), (1,)))
+        ds = p * (dp - delta) * scale                       # (bq, bk)
+        dk_acc[...] += _mm(ds.astype(q.dtype), q, ((0,), (0,)))
 
     @pl.when(inner == n_g * n_q_blocks - 1)
     def _finish():
@@ -233,9 +226,11 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, window=None, causal_shift=0,
     kp = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
     vp = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
     dop = jnp.pad(do, ((0, 0), (0, 0), (0, pad_q), (0, 0)))
-    lsep = jnp.pad(lse, ((0, 0), (0, 0), (0, pad_q)))
+    # row statistics travel as (.., S, 1) columns: a block's last two dims
+    # must tile (8, 128) or span the array, and a 1-wide last dim spans it
+    lsep = jnp.pad(lse, ((0, 0), (0, 0), (0, pad_q)))[..., None]
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    deltap = jnp.pad(delta, ((0, 0), (0, 0), (0, pad_q)))
+    deltap = jnp.pad(delta, ((0, 0), (0, 0), (0, pad_q)))[..., None]
 
     common = dict(block_q=bq, block_k=bk, sq_valid=Sq, skv_valid=Skv,
                   window=window, causal_shift=causal_shift)
@@ -247,8 +242,8 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, window=None, causal_shift=0,
             pl.BlockSpec((1, 1, bk, D), lambda b, h, qi, ki: (b, h // G, ki, 0)),
             pl.BlockSpec((1, 1, bk, D), lambda b, h, qi, ki: (b, h // G, ki, 0)),
             pl.BlockSpec((1, 1, bq, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, qi, ki: (b, h, qi)),
-            pl.BlockSpec((1, 1, bq), lambda b, h, qi, ki: (b, h, qi)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b, h, qi, ki: (b, h, qi, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, qi, ki: (b, h, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, nq * bq, D), q.dtype),
@@ -259,8 +254,6 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, window=None, causal_shift=0,
     def _q_map(b, kh, ki, i):
         return (b, kh * G + i // nq, i % nq, 0)
 
-    def _q_map1(b, kh, ki, i):
-        return (b, kh * G + i // nq, i % nq)
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, n_q_blocks=nq, n_g=G, **common),
@@ -270,8 +263,8 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, window=None, causal_shift=0,
             pl.BlockSpec((1, 1, bk, D), lambda b, kh, ki, i: (b, kh, ki, 0)),
             pl.BlockSpec((1, 1, bk, D), lambda b, kh, ki, i: (b, kh, ki, 0)),
             pl.BlockSpec((1, 1, bq, D), _q_map),
-            pl.BlockSpec((1, 1, bq), _q_map1),
-            pl.BlockSpec((1, 1, bq), _q_map1),
+            pl.BlockSpec((1, 1, bq, 1), _q_map),
+            pl.BlockSpec((1, 1, bq, 1), _q_map),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, D), lambda b, kh, ki, i: (b, kh, ki, 0)),

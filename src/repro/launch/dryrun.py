@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run driver (deliverable e).
 
 Lowers + compiles every (architecture x input shape) cell on the production
@@ -8,9 +5,9 @@ mesh — 16x16 single-pod and 2x16x16 multi-pod — and records
 memory_analysis / cost_analysis / loop-corrected HLO counters / roofline
 terms to benchmarks/results/dryrun/.
 
-The XLA_FLAGS line above MUST precede any jax import (jax locks the device
-count at first init); smoke tests and benches see 1 device because only this
-module sets it.
+Run as a program, it adds 512 host devices to XLA_FLAGS before JAX
+initializes its backends (the count is fixed then); importing the module
+changes nothing.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch qwen2-1.5b \
@@ -18,16 +15,16 @@ Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --all --mesh both
 """
 import argparse
-import dataclasses
 import json
+import os
 import sys
 import traceback
 
-import jax  # noqa: E402  (after XLA_FLAGS on purpose)
-
 from ..configs.base import (SHAPES, RunPolicy, default_preset, get_config,
                             list_archs)
+from .. import hw
 from ..core import counters
+from . import compile_cache
 from ..train.optimizer import OptConfig
 from .mesh import make_production_mesh
 from .steps import build_cell
@@ -67,13 +64,14 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     mesh = make_production_mesh(multi_pod=multi_pod)
     policy = policy or default_policy(cfg, shape)
     cell = build_cell(cfg, shape, policy, mesh, opt)
-    m = counters.measure_cell(cell)
+    m = counters.measure_cell(cell, hw.V5E)
     out = m.summary()
     out.update({"status": "ok", "mesh_kind": "multi" if multi_pod else "single"})
     return out
 
 
 def main(argv=None):
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None, choices=list(SHAPES))
@@ -140,4 +138,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    os.environ["XLA_FLAGS"] = " ".join(filter(None, [
+        os.environ.get("XLA_FLAGS"),
+        "--xla_force_host_platform_device_count=512"]))
     main()

@@ -19,49 +19,28 @@ def make_production_mesh(*, multi_pod: bool = False):
     return jax.sharding.Mesh(np.asarray(devices).reshape(shape), axes)
 
 
-def make_mesh(shape, axes):
-    return jax.make_mesh(tuple(shape), tuple(axes))
-
-
 def make_abstract_mesh(shape, axes):
-    """AbstractMesh across JAX API generations.
-
-    Older releases take ``AbstractMesh(shape_tuple)`` with ``shape_tuple`` a
-    tuple of ``(axis_name, size)`` pairs; newer ones take
-    ``AbstractMesh(shape, axis_names)``.
-    """
     from jax.sharding import AbstractMesh
-    shape = tuple(shape)
-    axes = tuple(axes)
-    try:
-        return AbstractMesh(shape, axes)
-    except TypeError:
-        return AbstractMesh(tuple(zip(axes, shape)))
+    return AbstractMesh(tuple(shape), tuple(axes))
 
 
 def shard_map(f, mesh, in_specs, out_specs, axis_names=None,
               check_vma: bool = False):
-    """``jax.shard_map`` across JAX API generations.
-
-    Newer JAX exposes ``jax.shard_map(..., axis_names=manual, check_vma=...)``;
-    older releases have ``jax.experimental.shard_map.shard_map`` with the
-    complementary ``auto`` axis set and ``check_rep``.
-    """
-    if hasattr(jax, "shard_map"):
-        kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=check_vma)
-        if axis_names is not None:
-            kwargs["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    manual = frozenset(axis_names) if axis_names is not None \
-        else frozenset(mesh.axis_names)
-    auto = frozenset(mesh.axis_names) - manual
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma, auto=auto)
+    """``jax.shard_map``; ``axis_names`` are the manual axes (all if None)."""
+    kwargs = dict(mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                  check_vma=check_vma)
+    if axis_names is not None:
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kwargs)
 
 
-def make_host_mesh():
-    """Whatever devices exist locally, as a 1D 'data' mesh (tests/examples)."""
+def make_host_mesh(model: int = 1):
+    """The local devices as a ("data", "model") mesh with ``model`` devices
+    on the model axis.  Its axes are Auto: the sharding rules place arrays
+    and the partitioner places the rest."""
     n = len(jax.devices())
-    return jax.make_mesh((n,), ("data",))
+    if n % model:
+        raise ValueError(f"{n} devices do not split into a model axis of "
+                         f"{model}")
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)

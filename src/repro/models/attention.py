@@ -114,7 +114,11 @@ def blocked_attention(q, k, v, positions_q, positions_kv, window=None, block=Non
         if window is not None:
             mask &= pt > pq - window
         s = jnp.where(mask, s, NEG_INF)
-        m_new = jnp.maximum(m, s.max(axis=-1))
+        # the running max only shifts the exponent, so the result does not
+        # depend on it and it carries no gradient (as in jax.nn.softmax).
+        # Left differentiable, its gradient divides by the number of scores
+        # equal to the max, and on the TPU in bf16 dq and dk came out NaN.
+        m_new = jax.lax.stop_gradient(jnp.maximum(m, s.max(axis=-1)))
         p = jnp.exp(s - m_new[..., None])
         corr = jnp.exp(m - m_new)
         l_new = l * corr + p.sum(axis=-1)

@@ -169,7 +169,9 @@ def apply_block_full(bt, p, x, positions, cfg: ModelConfig, policy: RunPolicy,
             q, k, v = attn.qkv_proj(p["attn"], h, cfg.n_heads, cfg.n_kv_heads,
                                     cfg.d_head, positions, cfg.rope_theta,
                                     cfg.use_rope)
-            if impl == "local":
+            if impl == "pallas":
+                o = attn.pallas_attention(q, k, v, cfg.window)
+            elif impl == "local":
                 o = attn.local_chunk_attention(q, k, v, positions, positions,
                                                cfg.window)
             elif impl == "blocked":

@@ -33,6 +33,7 @@ class Request:
     eos_id: int = -1            # -1: never
     out: list = dataclasses.field(default_factory=list)
     done: bool = False
+    logits: list | None = None  # a list here collects each token's logits
 
 
 def _update_slot(state, state1, slot: int):
@@ -87,6 +88,8 @@ class ServingEngine:
         self.key, k = jax.random.split(self.key)
         tok = int(sample_logits(logits, k, self.temperature)[0])
         req.out.append(tok)
+        if req.logits is not None:
+            req.logits.append(np.asarray(logits[0]))
         self.slot_req[slot] = req
         self.slot_pos[slot] = len(req.prompt)
         self.slot_last_tok[slot] = tok
@@ -116,6 +119,8 @@ class ServingEngine:
             req = self.slot_req[i]
             tok = int(nxt[i])
             req.out.append(tok)
+            if req.logits is not None:
+                req.logits.append(np.asarray(logits[i]))
             self.stats["tokens_out"] += 1
             self.slot_pos[i] += 1
             self.slot_last_tok[i] = tok

@@ -3,6 +3,7 @@ import pytest
 from repro.launch.mesh import make_abstract_mesh
 
 from repro.configs.base import SHAPES, RunPolicy, get_config
+from repro import hw
 from repro.core import analytic
 
 MESH = make_abstract_mesh((16, 16), ("data", "model"))
@@ -49,7 +50,8 @@ def test_decode_flops_per_token():
 def test_floors_positive_and_ordered():
     cfg = get_config("deepseek-67b")
     pol = RunPolicy(sharding_preset="tp", remat="full", n_microbatch=8)
-    f = analytic.step_floor_seconds(cfg, SHAPES["train_4k"], pol, MESH)
+    f = analytic.step_floor_seconds(cfg, SHAPES["train_4k"], pol, MESH,
+                                     hw.V5E)
     assert f["compute_s"] > 0 and f["memory_s"] > 0
     assert f["floor_s"] >= max(f["compute_s"], f["memory_s"],
                                f["collective_s"]) - 1e-12
@@ -72,3 +74,13 @@ def test_matmul_params_excludes_input_embedding():
     embed = cfg.vocab_size * cfg.d_model
     assert n_mm < n_all
     assert abs((n_all - n_mm) - embed) / embed < 0.2
+
+
+def test_chip_table_by_device_kind():
+    assert hw.chip_spec("TPU v5 lite").peak_flops_bf16 == 197e12
+    with pytest.raises(ValueError, match="no peaks"):
+        hw.chip_spec("TPU v99")
+    # host CPU meshes and stand-ins model the v5e
+    assert hw.chip_of_meshes({"single": object()}) is hw.V5E
+    assert hw.chip_of_meshes({"single": make_abstract_mesh((1,), ("x",))}) \
+        is hw.V5E

@@ -4,11 +4,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-    from hypothesis.extra import numpy as hnp
-except ImportError:          # container lacks hypothesis: seeded fallback
-    from hypstub import given, settings, st, hnp
+from hypothesis import given, settings, strategies as st
+from hypothesis.extra import numpy as hnp
 
 from repro.train.compression import _quantize_int8
 

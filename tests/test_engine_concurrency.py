@@ -107,6 +107,8 @@ def test_failed_compile_counts_as_attempt(monkeypatch):
     assert eng.measure(p) is None          # cached failure, no recharge
     assert eng.n_attempts == 1
     assert eng.n_failures == 1
+    assert eng.failures == [
+        "compile failed: RuntimeError: planted compile failure"]
     assert eng.n_compiles == 0
     s = eng.stats()
     assert s["n_attempts"] == 1 and s["n_failures"] == 1

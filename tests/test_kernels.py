@@ -117,10 +117,19 @@ def test_blocked_attention_matches_plain():
     k = rand(jax.random.fold_in(key, 1), (B, S, KV, dh), jnp.float32)
     v = rand(jax.random.fold_in(key, 2), (B, S, KV, dh), jnp.float32)
     pos = jnp.broadcast_to(jnp.arange(S), (B, S))
+    w = rand(jax.random.fold_in(key, 3), (B, S, KV, G, dh), jnp.float32)
     for win in (None, 20):
         a = blocked_attention(q, k, v, pos, pos, window=win, block=16)
         b = plain_attention(q, k, v, pos, pos, window=win)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+        ga, gb = (jax.grad(lambda q, k, v: (fn(q, k, v, pos, pos, window=win,
+                                                **kw) * w).sum(),
+                           argnums=(0, 1, 2))(q, k, v)
+                  for fn, kw in ((blocked_attention, {"block": 16}),
+                                 (plain_attention, {})))
+        for x, y in zip(ga, gb):
+            np.testing.assert_allclose(np.asarray(x), np.asarray(y),
+                                       atol=1e-4)
 
 
 def test_local_chunk_attention_exact_window():
@@ -134,3 +143,72 @@ def test_local_chunk_attention_exact_window():
     a = local_chunk_attention(q, k, v, pos, pos, window=W)
     b = plain_attention(q, k, v, pos, pos, window=W)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+
+
+def _op_args(name):
+    key = jax.random.PRNGKey(9)
+    if name == "attention":
+        return (rand(key, (1, 4, 32, 16), jnp.float32),
+                rand(key, (1, 2, 32, 16), jnp.float32),
+                rand(key, (1, 2, 32, 16), jnp.float32)), {"block_q": 16,
+                                                          "block_k": 16}
+    if name == "decode_attention":
+        pos = jnp.broadcast_to(jnp.arange(32, dtype=jnp.int32), (2, 32))
+        return (rand(key, (2, 4, 16), jnp.float32),
+                rand(key, (2, 2, 32, 16), jnp.float32),
+                rand(key, (2, 2, 32, 16), jnp.float32), pos,
+                jnp.array([31, 9], jnp.int32)), {"block_k": 16}
+    if name == "rglru":
+        return (jax.random.uniform(key, (1, 32, 16), jnp.float32, 0.5, 0.9),
+                rand(key, (1, 32, 16), jnp.float32)), {"block_s": 16}
+    return (rand(key, (1, 2, 32, 16), jnp.float32),
+            rand(key, (1, 2, 32, 16), jnp.float32),
+            rand(key, (1, 2, 32, 16), jnp.float32),
+            -jnp.exp(rand(key, (1, 2, 32, 16), jnp.float32)),
+            rand(key, (2, 16), jnp.float32)), {"chunk": 16}
+
+
+@pytest.mark.parametrize("name", ["attention", "decode_attention", "rglru",
+                                  "rwkv6"])
+def test_ops_interpret_only_on_request(name):
+    """Off the TPU a kernel runs only when interpret mode is asked for;
+    without the request it raises instead of falling back."""
+    from repro.kernels import ops
+    fn = getattr(ops, name)
+    args, kw = _op_args(name)
+    want = fn(*args, use_pallas=False)
+    got = fn(*args, use_pallas=True, interpret=True, **kw)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
+    with pytest.raises(ValueError, match="interpret"):
+        fn(*args, use_pallas=True, **kw)
+
+
+def test_prefill_with_cache_takes_the_flash_kernel(monkeypatch):
+    """Prefill that builds the cache routes use_pallas attention through the
+    flash kernel (interpret mode here) and agrees with the plain path; off
+    the TPU without interpret it raises rather than running plain."""
+    from repro.configs.all_archs import smoke_config
+    from repro.configs.base import RunPolicy
+    from repro.kernels import ops
+    from repro.models import api
+    from repro.train.train_step import make_prefill_step
+    cfg = smoke_config("qwen2-1.5b")
+    params = api.init(cfg, jax.random.PRNGKey(0))
+    batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 24), 0,
+                                          cfg.vocab_size, jnp.int32)}
+    f32 = dict(dtype="f32", remat="none")
+    want = make_prefill_step(cfg, RunPolicy(**f32), 32)(params, batch)
+    kernel = make_prefill_step(cfg, RunPolicy(use_pallas=True, **f32), 32)
+    with pytest.raises(ValueError, match="interpret"):
+        kernel(params, batch)
+    calls = []
+    attention = ops.attention
+
+    def interpreted(*a, **kw):
+        calls.append(1)
+        return attention(*a, interpret=True, **kw)
+    monkeypatch.setattr(ops, "attention", interpreted)
+    got = kernel(params, batch)
+    assert calls
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=1e-4)

@@ -14,10 +14,7 @@ import itertools
 import random
 
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # container lacks hypothesis: seeded fallback
-    from hypstub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs.base import ShapeSpec
 from repro.configs.all_archs import smoke_config

@@ -4,10 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # container lacks hypothesis: seeded fallback
-    from hypstub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.models.module import init_params
 from repro.models.moe import apply_moe, moe_specs

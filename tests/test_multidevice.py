@@ -76,6 +76,7 @@ def test_dryrun_single_cell_small_mesh():
         from repro.configs.all_archs import smoke_config
         from repro.launch.steps import build_cell
         from repro.core.counters import measure_cell
+        from repro.hw import V5E
         mesh = jax.sharding.Mesh(np.asarray(jax.devices()).reshape(4, 2),
                                  ("data", "model"))
         cfg = smoke_config("qwen2-1.5b")
@@ -83,7 +84,7 @@ def test_dryrun_single_cell_small_mesh():
                             ("decode", ShapeSpec("d", "decode", 128, 8))]:
             pol = RunPolicy(remat="dots", n_microbatch=2)
             cell = build_cell(cfg, shape, pol, mesh)
-            m = measure_cell(cell)
+            m = measure_cell(cell, V5E)
             assert m.roofline["bound_s"] > 0
             assert m.roofline["hlo_flops_per_dev"] > 0
             print("OK", kind, m.roofline["dominant"])

@@ -5,10 +5,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # container lacks hypothesis: seeded fallback
-    from hypstub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.models.rwkv6 import _wkv_scan, wkv_chunked, wkv_seq_parallel
 

@@ -1,10 +1,7 @@
 import random
 
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # container lacks hypothesis: seeded fallback
-    from hypstub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs.base import ShapeSpec
 from repro.configs.all_archs import smoke_config
@@ -140,6 +137,8 @@ def test_to_run_round_trip(space):
     assert shape.name == p["shape"]
     assert mesh_kind in ("single", "multi")
     assert policy.sharding_preset == p["preset"]
+    assert not policy.use_pallas          # not a searched factor
+    assert space.to_run({**p, "use_pallas": True})[2].use_pallas
 
 
 def test_restriction(space):

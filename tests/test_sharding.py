@@ -5,10 +5,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.launch.mesh import make_abstract_mesh
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:          # container lacks hypothesis: seeded fallback
-    from hypstub import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.launch.sharding import PRESETS, make_rules, spec_for
 

@@ -24,6 +24,7 @@ import random
 import pytest
 
 import repro.core.engine as engine_mod
+from repro import hw
 from repro.configs.all_archs import smoke_config
 from repro.configs.base import ShapeSpec
 from repro.core.engine import Engine
@@ -419,7 +420,7 @@ def test_two_channel_calibration_roundtrip(monkeypatch, tmp_path):
     doc.pop("lowered")
     with open(legacy, "w") as f:
         json.dump(doc, f)
-    sur = Surrogate(space, {"single": {}})
+    sur = Surrogate(space, {"single": {}}, hw.V5E)
     assert sur.load_calibration(legacy)
     assert sur.calibrator.n_observed == n0
     assert sur.lowered_calibrator.n_observed == 0

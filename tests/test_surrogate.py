@@ -16,6 +16,7 @@ import os
 import pytest
 
 from repro.core.benchscale import BENCH_SHAPES, bench_archs
+from repro import hw
 from repro.core.searchspace import SearchSpace
 from repro.core.surrogate import (Calibrator, KIND_COUNTER, SCREENED,
                                   Surrogate)
@@ -69,7 +70,7 @@ def load_fixture():
     space = SearchSpace(
         bench_archs(data["archs"]), BENCH_SHAPES,
         restrict={k: tuple(v) for k, v in data["restrict"].items()})
-    sur = Surrogate(space, data["mesh_shapes"])
+    sur = Surrogate(space, data["mesh_shapes"], hw.V5E)
     pairs = [(p, m) for p, m in data["pairs"] if m]
     if len(pairs) < 30:
         pytest.skip(f"fixture too small ({len(pairs)} pairs)")
@@ -169,8 +170,8 @@ def test_predict_batch_bit_identical_to_scalar():
     bad = dict(pts[1])
     bad["mesh"] = "nonexistent"
     pts.insert(5, bad)                             # infeasible row
-    scalar = Surrogate(space, mesh_shapes)
-    vector = Surrogate(space, mesh_shapes)
+    scalar = Surrogate(space, mesh_shapes, hw.V5E)
+    vector = Surrogate(space, mesh_shapes, hw.V5E)
     want = [scalar.predict(p, calibrated=False) for p in pts]
     got = vector.predict_batch(pts, calibrated=False)
     assert want == got

@@ -1,5 +1,7 @@
 """End-to-end behaviour tests: training convergence, checkpoint-resume
 determinism, serving, data pipeline."""
+import os
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -117,3 +119,60 @@ def test_serving_greedy_matches_decode_path():
         ref.append(t)
         toks.append(t)
     assert out == ref, (out, ref)
+
+
+def test_serving_logits_match_full_forward():
+    """Logits a request collects (prefill, then cache decode on a shared
+    batch) equal one full forward pass over prompt plus output."""
+    cfg = smoke_config("qwen2-1.5b")
+    pol = RunPolicy(remat="none", dtype="f32")
+    params = api.init(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(cfg, pol, params, n_slots=2, cache_len=32)
+    reqs = [Request(rid=i, prompt=np.arange(3 + 4 * i, dtype=np.int32),
+                    max_new_tokens=5) for i in range(3)]
+    reqs[1].logits = []
+    for r in reqs:
+        eng.add_request(r)
+    eng.run()
+    r = reqs[1]
+    toks = np.concatenate([r.prompt, np.asarray(r.out[:-1], np.int32)])
+    logits, _ = api.forward(params, {"tokens": jnp.asarray([toks])}, cfg, pol)
+    want = np.asarray(logits[0, len(r.prompt) - 1:])
+    np.testing.assert_allclose(np.stack(r.logits), want, atol=1e-4)
+    assert reqs[0].logits is None
+
+
+def test_launcher_trains_checkpoints_and_resumes_on_request(tmp_path,
+                                                            monkeypatch):
+    """launch/train.main: finite losses, a checkpoint in --ckpt-dir, a
+    fresh start unless --resume, and --resume continues the step count."""
+    from repro.launch import train
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "jc"))
+    ck = str(tmp_path / "ck")
+    argv = ["--smoke", "--steps", "2", "--seq", "16", "--batch", "2",
+            "--ckpt-dir", ck, "--ckpt-every", "2"]
+    first = train.main(argv)
+    assert len(first["losses"]) == 2
+    assert all(np.isfinite(first["losses"]))
+    assert CheckpointManager(ck).list_steps() == [2]
+    again = train.main(argv)
+    assert again["losses"] == first["losses"]
+    train.main(argv + ["--resume"])
+    assert CheckpointManager(ck).list_steps() == [2, 4]
+
+
+def test_compile_cache_dir_is_fixed_or_the_env_var(monkeypatch, tmp_path):
+    from repro.launch import compile_cache
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compile_cache.enable() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert compile_cache.enable() == compile_cache.CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == compile_cache.CACHE_DIR
+        assert compile_cache.CACHE_DIR == os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -1,0 +1,101 @@
+"""Compiles for a described TPU v5e, without a chip: the Pallas kernels at the
+widths the chip smoke runs them, and one qwen2-1.5b prefill cell on the
+kernel path.  Mosaic refuses here what interpret mode accepts: block shapes
+off the (8, 128) tiling, more VMEM than a kernel may use, primitives it
+cannot lower.  Nothing runs, so these say nothing about results or times.
+
+The topology is described inside a fixture: only one process may load the
+TPU library, so describing it at import would break the other test workers.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, SingleDeviceSharding
+
+from repro import hw
+from repro.configs.base import RunPolicy, ShapeSpec, get_config
+from repro.core.counters import measure_cell
+from repro.kernels.decode_attention import flash_decode
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.rglru_scan import rglru_scan
+from repro.kernels.rwkv6_kernel import rwkv6_wkv
+from repro.launch.steps import build_cell
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep it out
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _flash_fwd(q, k, v):
+    return flash_attention(q, k, v, None, 0, 128, 128, False)
+
+
+def _flash_bwd(q, k, v, w):
+    def loss(q, k, v):
+        return (_flash_fwd(q, k, v).astype(jnp.float32) * w).sum()
+    return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+
+H, KVH, D, S, T = 12, 2, 128, 2048, 2048     # qwen2-1.5b heads, S, cache
+BF, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+KERNELS = {
+    "flash_fwd": (_flash_fwd, [((1, H, S, D), BF), ((1, KVH, S, D), BF),
+                               ((1, KVH, S, D), BF)]),
+    "flash_bwd": (_flash_bwd, [((1, H, S, D), BF), ((1, KVH, S, D), BF),
+                               ((1, KVH, S, D), BF), ((1, H, S, D), BF)]),
+    "flash_decode": (lambda *a: flash_decode(*a, interpret=False),
+                     [((4, H, D), BF), ((4, KVH, T, D), BF),
+                      ((4, KVH, T, D), BF), ((4, T), I32), ((4,), I32)]),
+    # recurrentgemma-2b's RG-LRU width
+    "rglru_scan": (lambda a, b: rglru_scan(a, b, interpret=False),
+                   [((2, S, 2560), F32), ((2, S, 2560), F32)]),
+    # rwkv6-7b: 64 heads of 64
+    "rwkv6_wkv": (lambda *a: rwkv6_wkv(*a, interpret=False),
+                  [((1, 64, S, 64), BF)] * 4 + [((64, 64), BF)]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(KERNELS))
+def test_kernel_compiles_for_v5e(topo, name):
+    fn, shapes = KERNELS[name]
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_qwen2_prefill_cell_compiles_with_kernels(topo):
+    """The measurement path on one described chip: the prefill cell's
+    compiled module calls the flash kernel (``tpu_custom_call`` in its
+    text) and its memory_analysis() peak fits the chip."""
+    mesh = Mesh(np.asarray(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    chip = hw.chip_of_meshes({"single": mesh})
+    assert chip is hw.chip_spec("TPU v5 lite")
+    cell = build_cell(get_config("qwen2-1.5b"),
+                      ShapeSpec("prefill", "prefill", 2048, 1),
+                      RunPolicy(use_pallas=True, remat="none",
+                                params_f32=False), mesh)
+    m = measure_cell(cell, chip)
+    assert m.tpu_custom_calls > 0
+    assert 0 < m.memory["peak_bytes"] < chip.hbm_bytes

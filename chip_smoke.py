@@ -37,7 +37,8 @@ RUN_DIR = os.path.join(ROOT, "runs", "chip_smoke")
 #   weights (and, backward, their gradients) to bf16 for the MXU, which
 #   adds about as much again: 2e-2 leaves room, while a wrong mask, block
 #   offset or softmax rescale moves the result by O(1).
-# - rglru_scan runs the reference's own f32 recurrence a_t * h + b_t,
+# - rglru_scan takes f32 gates (the model computes them in f32, in bf16
+#   runs too) and runs the reference's own recurrence a_t * h + b_t,
 #   elementwise, in the same order: only rounding of one multiply-add can
 #   differ, so 1e-5.
 # - rwkv6_wkv reassociates the recurrence chunk-wise and runs its f32

@@ -45,7 +45,8 @@ def main():
     ap.add_argument("--steps", type=int, default=200)
     ap.add_argument("--seq", type=int, default=256)
     ap.add_argument("--batch", type=int, default=16)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm")
+    ap.add_argument("--ckpt-dir", default=os.path.join(
+        compile_cache.ROOT, "runs", "train_lm", "ckpt"))
     ap.add_argument("--ckpt-every", type=int, default=50)
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()

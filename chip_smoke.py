@@ -103,29 +103,6 @@ def check(ok, msg):
         raise PhaseFailed(msg)
 
 
-class CompileLog:
-    """Backend compile time and persistent-cache hits, from JAX's events."""
-
-    def __init__(self):
-        self.seconds = 0.0
-        self.programs = 0
-        self.cache_hits = 0
-
-    def on_duration(self, event, duration, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += duration
-            self.programs += 1
-
-    def on_event(self, event, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-
-    def install(self):
-        import jax
-        jax.monitoring.register_event_duration_secs_listener(self.on_duration)
-        jax.monitoring.register_event_listener(self.on_event)
-
-
 def rel_err(got, want):
     import jax.numpy as jnp
     got = jnp.asarray(got, jnp.float32)
@@ -411,9 +388,9 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     from repro.launch import compile_cache
+    from repro.runtime.spans import CompileLog
     cache_dir = compile_cache.enable()
-    log = CompileLog()
-    log.install()
+    log = CompileLog().install()
     t0 = time.perf_counter()
     run = run_four_chips if args.chips == 4 else run_one_chip
     devices = run(PLAN, args.seed)

@@ -94,5 +94,6 @@ def flash_decode(q, k, v, pos, qpos, *, window=None, block_k=512,
             pltpu.VMEM((1, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_attn",
     )(q[:, :, None, :], kp, vp, posp, qpos3)
     return out[:, :, 0]

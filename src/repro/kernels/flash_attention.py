@@ -124,6 +124,7 @@ def flash_attention_fwd(q, k, v, *, window=None, causal_shift=0,
             pltpu.VMEM((bq, 1), jnp.float32),   # l
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qp, kp, vp)
     return o[:, :, :Sq], lse[:, :, :Sq, 0]
 
@@ -249,6 +250,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, window=None, causal_shift=0,
         out_shape=jax.ShapeDtypeStruct((B, H, nq * bq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qp, kp, vp, dop, lsep, deltap)
 
     def _q_map(b, kh, ki, i):
@@ -277,6 +279,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, window=None, causal_shift=0,
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qp, kp, vp, dop, lsep, deltap)
     return dq[:, :, :Sq], dk[:, :, :Skv], dv[:, :, :Skv]
 

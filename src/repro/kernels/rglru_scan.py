@@ -54,5 +54,6 @@ def rglru_scan(a, b, *, block_s=256, interpret=False):
         out_shape=jax.ShapeDtypeStruct((B, ns * bs, W), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, bw), jnp.float32)],
         interpret=interpret,
+        name="rglru_scan",
     )(ap, bp)
     return out[:, :S]

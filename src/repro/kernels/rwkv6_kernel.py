@@ -100,5 +100,6 @@ def rwkv6_wkv(r, k, v, w_log, u, *, chunk=64, interpret=False):
         out_shape=jax.ShapeDtypeStruct((B, H, nc * C, hs), jnp.float32),
         scratch_shapes=[pltpu.VMEM((hs, hs), jnp.float32)],
         interpret=interpret,
+        name="rwkv6_scan",
     )(rp, kp, vp, wp, u[:, None, :])
     return out[:, :, :S]

@@ -204,12 +204,15 @@ def decode_attention(p, cache, x, position, *, n_heads, n_kv, d_head,
     # masked-where write: elementwise over the cache slice, so it partitions
     # cleanly under cache_seq sharding (a scatter forces gather/select
     # plumbing; see EXPERIMENTS.md §Perf deepseek decode iteration 4)
-    hit = (jnp.arange(T, dtype=jnp.int32)[None, :] == slot[:, None])
-    new_cache = {
-        "k": jnp.where(hit[..., None, None], k.astype(cache["k"].dtype), cache["k"]),
-        "v": jnp.where(hit[..., None, None], v.astype(cache["v"].dtype), cache["v"]),
-        "pos": jnp.where(hit, position[:, None], cache["pos"]),
-    }
+    with jax.named_scope("kv_cache"):
+        hit = (jnp.arange(T, dtype=jnp.int32)[None, :] == slot[:, None])
+        new_cache = {
+            "k": jnp.where(hit[..., None, None], k.astype(cache["k"].dtype),
+                           cache["k"]),
+            "v": jnp.where(hit[..., None, None], v.astype(cache["v"].dtype),
+                           cache["v"]),
+            "pos": jnp.where(hit, position[:, None], cache["pos"]),
+        }
     kk, vv, pos_kv = new_cache["k"], new_cache["v"], new_cache["pos"]
     g = n_heads // n_kv
     q = maybe_constrain(q, ("batch", None, "kv_heads", "heads", "head_dim"))

@@ -6,6 +6,12 @@ VLM patch-prefix and multi-codebook audio frontends.  Layers are grouped
 into repeating *pattern units* (e.g. ("rec","rec","attn") for
 recurrentgemma); units are either scanned (stacked params, production
 default) or unrolled (D3 search factor).
+
+Named scopes mark the layers in the compiled programs' metadata, and so in
+the device trace: ``embed``, ``layers`` (the unit loop or scan, and the
+tail), ``attn`` and ``mlp`` (each sublayer of an attention block with its
+norm and residual), ``attn/kv_cache`` (the cache written), ``final_norm``,
+``unembed`` and ``loss``.
 """
 from __future__ import annotations
 
@@ -99,6 +105,7 @@ def _unembed_specs(cfg):
 
 # ------------------------------------------------------------------ embedding
 
+@jax.named_scope("embed")
 def embed_tokens(params, cfg: ModelConfig, batch, compute_dtype):
     """Returns (x (B,S,D), positions (B,S), label_mask_prefix)."""
     table = params["embed"]["table"]
@@ -123,6 +130,7 @@ def embed_tokens(params, cfg: ModelConfig, batch, compute_dtype):
     return x, positions
 
 
+@jax.named_scope("unembed")
 def unembed_logits(params, cfg: ModelConfig, x):
     table = (params["embed"] if cfg.tie_embeddings else params["unembed"])["table"]
     if cfg.frontend == "encodec":
@@ -158,41 +166,45 @@ def apply_block_full(bt, p, x, positions, cfg: ModelConfig, policy: RunPolicy,
     state = None
     S = x.shape[1]
     if bt == "attn":
-        h = apply_norm(p["ln1"], x, cfg.norm)
-        impl = _resolve_attn_impl(cfg, policy, S)
-        kw = dict(n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, d_head=cfg.d_head,
-                  rope_theta=cfg.rope_theta, window=cfg.window,
-                  use_rope=cfg.use_rope)
-        if cache_len is None:
-            a = attn.full_attention(p["attn"], h, positions, impl=impl, **kw)
-        else:
-            q, k, v = attn.qkv_proj(p["attn"], h, cfg.n_heads, cfg.n_kv_heads,
-                                    cfg.d_head, positions, cfg.rope_theta,
-                                    cfg.use_rope)
-            if impl == "pallas":
-                o = attn.pallas_attention(q, k, v, cfg.window)
-            elif impl == "local":
-                o = attn.local_chunk_attention(q, k, v, positions, positions,
-                                               cfg.window)
-            elif impl == "blocked":
-                o = attn.blocked_attention(q, k, v, positions, positions,
-                                           cfg.window)
+        with jax.named_scope("attn"):
+            h = apply_norm(p["ln1"], x, cfg.norm)
+            impl = _resolve_attn_impl(cfg, policy, S)
+            kw = dict(n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
+                      d_head=cfg.d_head, rope_theta=cfg.rope_theta,
+                      window=cfg.window, use_rope=cfg.use_rope)
+            if cache_len is None:
+                a = attn.full_attention(p["attn"], h, positions, impl=impl,
+                                        **kw)
             else:
-                o = attn.plain_attention(q, k, v, positions, positions, cfg.window)
-            a = attn.out_proj(p["attn"], o)
-            state = _cache_from_kv(k, v, positions, cache_len, cfg)
-        x = x + a
-        h2 = apply_norm(p["ln2"], x, cfg.norm)
-        if cfg.n_experts:
-            m, moe_aux = moe_mod.apply_moe(p["mlp"], h2, top_k=cfg.top_k,
-                                           act=cfg.act,
-                                           capacity_factor=policy.capacity_factor)
-            aux = jnp.stack([moe_aux["lb_loss"], moe_aux["dropped_frac"]])
-        elif "wi" in p["mlp"]:
-            m = apply_plain_mlp(p["mlp"], h2, cfg.act)
-        else:
-            m = apply_glu_mlp(p["mlp"], h2, cfg.act)
-        x = x + m
+                q, k, v = attn.qkv_proj(p["attn"], h, cfg.n_heads,
+                                        cfg.n_kv_heads, cfg.d_head, positions,
+                                        cfg.rope_theta, cfg.use_rope)
+                if impl == "pallas":
+                    o = attn.pallas_attention(q, k, v, cfg.window)
+                elif impl == "local":
+                    o = attn.local_chunk_attention(q, k, v, positions,
+                                                   positions, cfg.window)
+                elif impl == "blocked":
+                    o = attn.blocked_attention(q, k, v, positions, positions,
+                                               cfg.window)
+                else:
+                    o = attn.plain_attention(q, k, v, positions, positions,
+                                             cfg.window)
+                a = attn.out_proj(p["attn"], o)
+                state = _cache_from_kv(k, v, positions, cache_len, cfg)
+            x = x + a
+        with jax.named_scope("mlp"):
+            h2 = apply_norm(p["ln2"], x, cfg.norm)
+            if cfg.n_experts:
+                m, moe_aux = moe_mod.apply_moe(
+                    p["mlp"], h2, top_k=cfg.top_k, act=cfg.act,
+                    capacity_factor=policy.capacity_factor)
+                aux = jnp.stack([moe_aux["lb_loss"], moe_aux["dropped_frac"]])
+            elif "wi" in p["mlp"]:
+                m = apply_plain_mlp(p["mlp"], h2, cfg.act)
+            else:
+                m = apply_glu_mlp(p["mlp"], h2, cfg.act)
+            x = x + m
     elif bt == "rec":
         h = apply_norm(p["ln1"], x, cfg.norm)
         if cache_len is None:
@@ -230,6 +242,7 @@ def apply_block_full(bt, p, x, positions, cfg: ModelConfig, policy: RunPolicy,
     return x, aux, state
 
 
+@jax.named_scope("kv_cache")
 def _cache_from_kv(k, v, positions, cache_len, cfg):
     B, S = k.shape[:2]
     if cfg.window is not None and cache_len < S:
@@ -321,21 +334,24 @@ def apply_block_decode(bt, p, state, x, position, cfg: ModelConfig,
                        policy: RunPolicy | None = None):
     cf = policy.capacity_factor if policy is not None else 1.25
     if bt == "attn":
-        h = apply_norm(p["ln1"], x, cfg.norm)
-        o, new_cache = attn.decode_attention(
-            p["attn"], state, h, position, n_heads=cfg.n_heads,
-            n_kv=cfg.n_kv_heads, d_head=cfg.d_head, rope_theta=cfg.rope_theta,
-            window=cfg.window, use_rope=cfg.use_rope)
-        x = x + attn.out_proj(p["attn"], o)
-        h2 = apply_norm(p["ln2"], x, cfg.norm)
-        if cfg.n_experts:
-            m, _ = moe_mod.apply_moe(p["mlp"], h2, top_k=cfg.top_k,
-                                     act=cfg.act, capacity_factor=cf)
-        elif "wi" in p["mlp"]:
-            m = apply_plain_mlp(p["mlp"], h2, cfg.act)
-        else:
-            m = apply_glu_mlp(p["mlp"], h2, cfg.act)
-        return x + m, new_cache
+        with jax.named_scope("attn"):
+            h = apply_norm(p["ln1"], x, cfg.norm)
+            o, new_cache = attn.decode_attention(
+                p["attn"], state, h, position, n_heads=cfg.n_heads,
+                n_kv=cfg.n_kv_heads, d_head=cfg.d_head,
+                rope_theta=cfg.rope_theta, window=cfg.window,
+                use_rope=cfg.use_rope)
+            x = x + attn.out_proj(p["attn"], o)
+        with jax.named_scope("mlp"):
+            h2 = apply_norm(p["ln2"], x, cfg.norm)
+            if cfg.n_experts:
+                m, _ = moe_mod.apply_moe(p["mlp"], h2, top_k=cfg.top_k,
+                                         act=cfg.act, capacity_factor=cf)
+            elif "wi" in p["mlp"]:
+                m = apply_plain_mlp(p["mlp"], h2, cfg.act)
+            else:
+                m = apply_glu_mlp(p["mlp"], h2, cfg.act)
+            return x + m, new_cache
     if bt == "rec":
         h = apply_norm(p["ln1"], x, cfg.norm)
         r, new_state = rg.decode_rglru(p["rec"], state, h, n_blocks=cfg.n_heads)
@@ -443,34 +459,36 @@ def forward(params, batch, cfg: ModelConfig, policy: RunPolicy,
 
     unit_fn_r = _remat_wrap(unit_fn, policy)
 
-    if policy.scan_layers and n_units > 1:
-        def scan_body(carry, unit_params):
-            x, acc = carry
-            x, aux, states = unit_fn_r(x, unit_params, positions)
-            return (x, acc + aux), states
-        (x, aux), states = jax.lax.scan(
-            scan_body, (x, jnp.zeros((2,), jnp.float32)), cparams["units"])
-    else:
-        aux = jnp.zeros((2,), jnp.float32)
-        states_list = []
-        for u in range(n_units):
-            up = jax.tree.map(lambda a: a[u], cparams["units"])
-            x, a, st = unit_fn_r(x, up, positions)
+    with jax.named_scope("layers"):
+        if policy.scan_layers and n_units > 1:
+            def scan_body(carry, unit_params):
+                x, acc = carry
+                x, aux, states = unit_fn_r(x, unit_params, positions)
+                return (x, acc + aux), states
+            (x, aux), states = jax.lax.scan(
+                scan_body, (x, jnp.zeros((2,), jnp.float32)), cparams["units"])
+        else:
+            aux = jnp.zeros((2,), jnp.float32)
+            states_list = []
+            for u in range(n_units):
+                up = jax.tree.map(lambda a: a[u], cparams["units"])
+                x, a, st = unit_fn_r(x, up, positions)
+                aux = aux + a
+                states_list.append(st)
+            states = jax.tree.map(lambda *xs: jnp.stack(xs), *states_list) \
+                if (cl is not None and states_list) else None
+
+        tail_states = {}
+        for i in range(tail):
+            bt = pattern[i]
+            x, a, st = apply_block_full(bt, cparams["tail"][f"t{i}"], x,
+                                        positions, cfg, policy, cache_len=cl)
             aux = aux + a
-            states_list.append(st)
-        states = jax.tree.map(lambda *xs: jnp.stack(xs), *states_list) \
-            if (cl is not None and states_list) else None
+            if cl is not None:
+                tail_states[f"t{i}"] = st
 
-    tail_states = {}
-    for i in range(tail):
-        bt = pattern[i]
-        x, a, st = apply_block_full(bt, cparams["tail"][f"t{i}"], x, positions,
-                                    cfg, policy, cache_len=cl)
-        aux = aux + a
-        if cl is not None:
-            tail_states[f"t{i}"] = st
-
-    x = apply_norm(cparams["final_norm"], x, cfg.norm)
+    with jax.named_scope("final_norm"):
+        x = apply_norm(cparams["final_norm"], x, cfg.norm)
     if return_cache:
         last = x[:, -1]
         logits = unembed_logits(cparams, cfg, last)
@@ -503,40 +521,44 @@ def decode_step(params, state, batch, cfg: ModelConfig, policy: RunPolicy):
             new_states[f"b{i}"] = st
         return x, new_states
 
-    if policy.scan_layers and n_units > 1:
-        def scan_body(x, inp):
-            unit_params, unit_state = inp
-            x, ns = unit_fn(x, unit_params, unit_state)
-            return x, ns
-        x, new_unit_states = jax.lax.scan(
-            scan_body, x, (cparams["units"], state["units"]))
-    else:
-        ns_list = []
-        for u in range(n_units):
-            up = jax.tree.map(lambda a: a[u], cparams["units"])
-            us = jax.tree.map(lambda a: a[u], state["units"])
-            x, ns = unit_fn(x, up, us)
-            ns_list.append(ns)
-        new_unit_states = jax.tree.map(lambda *xs: jnp.stack(xs), *ns_list)
+    with jax.named_scope("layers"):
+        if policy.scan_layers and n_units > 1:
+            def scan_body(x, inp):
+                unit_params, unit_state = inp
+                x, ns = unit_fn(x, unit_params, unit_state)
+                return x, ns
+            x, new_unit_states = jax.lax.scan(
+                scan_body, x, (cparams["units"], state["units"]))
+        else:
+            ns_list = []
+            for u in range(n_units):
+                up = jax.tree.map(lambda a: a[u], cparams["units"])
+                us = jax.tree.map(lambda a: a[u], state["units"])
+                x, ns = unit_fn(x, up, us)
+                ns_list.append(ns)
+            new_unit_states = jax.tree.map(lambda *xs: jnp.stack(xs),
+                                           *ns_list)
 
-    new_state = {"units": new_unit_states}
-    if tail:
-        new_tail = {}
-        for i in range(tail):
-            bt = pattern[i]
-            x, st = apply_block_decode(bt, cparams["tail"][f"t{i}"],
-                                       state["tail"][f"t{i}"], x, position,
-                                       cfg, policy)
-            new_tail[f"t{i}"] = st
-        new_state["tail"] = new_tail
+        new_state = {"units": new_unit_states}
+        if tail:
+            new_tail = {}
+            for i in range(tail):
+                bt = pattern[i]
+                x, st = apply_block_decode(bt, cparams["tail"][f"t{i}"],
+                                           state["tail"][f"t{i}"], x,
+                                           position, cfg, policy)
+                new_tail[f"t{i}"] = st
+            new_state["tail"] = new_tail
 
-    x = apply_norm(cparams["final_norm"], x, cfg.norm)
+    with jax.named_scope("final_norm"):
+        x = apply_norm(cparams["final_norm"], x, cfg.norm)
     logits = unembed_logits(cparams, cfg, x[:, 0])
     return logits, new_state
 
 
 # ----------------------------------------------------------------------- loss
 
+@jax.named_scope("loss")
 def lm_loss(logits, labels):
     """Cross-entropy with mask (labels < 0 ignored). logits f32."""
     V = logits.shape[-1]

@@ -5,6 +5,15 @@ A fixed pool of ``n_slots`` decode lanes; each incoming request is prefilled
 cache, and advanced by the shared batched decode step.  Lanes free up on EOS
 or max_new_tokens — continuous-batching-lite, the serving pattern the
 decode_* shape cells lower.
+
+Each step and its phases are spans of ``runtime/spans`` (``serve.*``; off
+unless the engine's tracer is on, by default while JAX's profiler records):
+``serve.enqueue`` (no length, in ``add_request``), ``serve.step``, and in
+it ``serve.admit`` (``serve.prefill``, ``serve.slot_update``, and
+``serve.first_token``, the blocking fetch of the request's first token),
+``serve.upload`` (the lanes' tokens and positions), ``serve.decode`` (the
+dispatch), ``serve.sample`` (the blocking fetch of the next tokens) and
+``serve.lanes`` (the lanes' bookkeeping).  A request's spans carry its id.
 """
 from __future__ import annotations
 
@@ -16,6 +25,7 @@ import numpy as np
 
 from ..configs.base import ModelConfig, RunPolicy
 from ..models import api
+from ..runtime import spans as spans_mod
 from ..train.train_step import make_decode_step, make_prefill_step
 
 
@@ -57,7 +67,7 @@ def _update_slot(state, state1, slot: int):
 class ServingEngine:
     def __init__(self, cfg: ModelConfig, policy: RunPolicy, params,
                  n_slots: int = 4, cache_len: int = 256, seed: int = 0,
-                 temperature: float = 0.0):
+                 temperature: float = 0.0, spans: spans_mod.Spans | None = None):
         if cfg.frontend == "encodec":
             raise NotImplementedError("serving engine drives token-stream archs")
         self.cfg, self.policy, self.params = cfg, policy, params
@@ -76,24 +86,32 @@ class ServingEngine:
         self.pending: list[Request] = []
         self.completed: list[Request] = []
         self.stats = {"prefills": 0, "decode_steps": 0, "tokens_out": 0}
+        self.spans = spans_mod.PROGRAM if spans is None else spans
 
     # ------------------------------------------------------------------ admin
     def add_request(self, req: Request):
+        self.spans.mark("serve.enqueue", rid=req.rid)
         self.pending.append(req)
 
     def _insert(self, slot: int, req: Request):
-        prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
-        logits, state1 = self.prefill(self.params, {"tokens": prompt})
-        self.state = self._update(self.state, state1, slot)
-        self.key, k = jax.random.split(self.key)
-        tok = int(sample_logits(logits, k, self.temperature)[0])
-        req.out.append(tok)
-        if req.logits is not None:
-            req.logits.append(np.asarray(logits[0]))
-        self.slot_req[slot] = req
-        self.slot_pos[slot] = len(req.prompt)
-        self.slot_last_tok[slot] = tok
-        self.stats["prefills"] += 1
+        sp = self.spans
+        with sp("serve.admit", rid=req.rid, slot=slot):
+            with sp("serve.prefill", rid=req.rid, len=len(req.prompt)):
+                prompt = jnp.asarray(req.prompt, jnp.int32)[None, :]
+                logits, state1 = self.prefill(self.params, {"tokens": prompt})
+            with sp("serve.slot_update", rid=req.rid, slot=slot):
+                self.state = self._update(self.state, state1, slot)
+            with sp("serve.first_token", rid=req.rid):
+                self.key, k = jax.random.split(self.key)
+                tok = int(sample_logits(logits, k, self.temperature)[0])
+            sp.count("serve.prompt_tokens", len(req.prompt))
+            req.out.append(tok)
+            if req.logits is not None:
+                req.logits.append(np.asarray(logits[0]))
+            self.slot_req[slot] = req
+            self.slot_pos[slot] = len(req.prompt)
+            self.slot_last_tok[slot] = tok
+            self.stats["prefills"] += 1
 
     def _free_slots(self):
         return [i for i, r in enumerate(self.slot_req) if r is None]
@@ -101,36 +119,44 @@ class ServingEngine:
     # ------------------------------------------------------------------- step
     def step(self):
         """Admit pending requests, run one batched decode step."""
-        for slot in self._free_slots():
-            if not self.pending:
-                break
-            self._insert(slot, self.pending.pop(0))
-        active = [i for i, r in enumerate(self.slot_req) if r is not None]
-        if not active:
-            return False
-        toks = jnp.asarray(self.slot_last_tok, jnp.int32)[:, None]
-        pos = jnp.asarray(self.slot_pos, jnp.int32)
-        logits, self.state = self.decode(self.params, self.state,
-                                         {"tokens": toks, "position": pos})
-        self.stats["decode_steps"] += 1
-        self.key, k = jax.random.split(self.key)
-        nxt = np.asarray(sample_logits(logits, k, self.temperature))
-        for i in active:
-            req = self.slot_req[i]
-            tok = int(nxt[i])
-            req.out.append(tok)
-            if req.logits is not None:
-                req.logits.append(np.asarray(logits[i]))
-            self.stats["tokens_out"] += 1
-            self.slot_pos[i] += 1
-            self.slot_last_tok[i] = tok
-            hit_eos = (req.eos_id >= 0 and tok == req.eos_id)
-            if hit_eos or len(req.out) >= req.max_new_tokens \
-                    or self.slot_pos[i] >= self.cache_len - 1:
-                req.done = True
-                self.completed.append(req)
-                self.slot_req[i] = None
-        return True
+        sp = self.spans
+        sp.tick()
+        with sp("serve.step"):
+            for slot in self._free_slots():
+                if not self.pending:
+                    break
+                self._insert(slot, self.pending.pop(0))
+            active = [i for i, r in enumerate(self.slot_req) if r is not None]
+            if not active:
+                return False
+            with sp("serve.upload"):
+                toks = jnp.asarray(self.slot_last_tok, jnp.int32)[:, None]
+                pos = jnp.asarray(self.slot_pos, jnp.int32)
+            with sp("serve.decode", active=len(active)):
+                logits, self.state = self.decode(
+                    self.params, self.state, {"tokens": toks, "position": pos})
+            self.stats["decode_steps"] += 1
+            sp.count("serve.lanes_decoded", len(active))
+            with sp("serve.sample"):
+                self.key, k = jax.random.split(self.key)
+                nxt = np.asarray(sample_logits(logits, k, self.temperature))
+            with sp("serve.lanes"):
+                for i in active:
+                    req = self.slot_req[i]
+                    tok = int(nxt[i])
+                    req.out.append(tok)
+                    if req.logits is not None:
+                        req.logits.append(np.asarray(logits[i]))
+                    self.stats["tokens_out"] += 1
+                    self.slot_pos[i] += 1
+                    self.slot_last_tok[i] = tok
+                    hit_eos = (req.eos_id >= 0 and tok == req.eos_id)
+                    if hit_eos or len(req.out) >= req.max_new_tokens \
+                            or self.slot_pos[i] >= self.cache_len - 1:
+                        req.done = True
+                        self.completed.append(req)
+                        self.slot_req[i] = None
+            return True
 
     def run(self, max_steps: int = 1000):
         steps = 0

@@ -86,6 +86,7 @@ def clip_by_global_norm(grads, max_norm):
     return jax.tree.map(lambda g: (g.astype(jnp.float32) * scale), grads), n
 
 
+@jax.named_scope("optimizer")
 def opt_update(opt: OptConfig, grads, state, params):
     """Returns (new_params, new_state, stats)."""
     grads, gnorm = clip_by_global_norm(grads, opt.grad_clip)

@@ -164,10 +164,13 @@ def test_launcher_trains_checkpoints_and_resumes_on_request(tmp_path,
 def test_compile_cache_dir_is_fixed_or_the_env_var(monkeypatch, tmp_path):
     from repro.launch import compile_cache
     prev = jax.config.jax_compilation_cache_dir
+    prev_meta = jax.config.jax_compilation_cache_include_metadata_in_key
     try:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         assert compile_cache.enable() == str(tmp_path)
         assert jax.config.jax_compilation_cache_dir == prev
+        # scope names are part of the key, so a cached program keeps its own
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         assert compile_cache.enable() == compile_cache.CACHE_DIR
         assert jax.config.jax_compilation_cache_dir == compile_cache.CACHE_DIR
@@ -176,3 +179,5 @@ def test_compile_cache_dir_is_fixed_or_the_env_var(monkeypatch, tmp_path):
             ".jax_cache")
     finally:
         jax.config.update("jax_compilation_cache_dir", prev)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                          prev_meta)

@@ -8,6 +8,7 @@ The topology is described inside a fixture: only one process may load the
 TPU library, so describing it at import would break the other test workers.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -59,30 +60,43 @@ def _flash_bwd(q, k, v, w):
 H, KVH, D, S, T = 12, 2, 128, 2048, 2048     # qwen2-1.5b heads, S, cache
 BF, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
+# each entry: the function, its argument shapes, and the names its Pallas
+# kernels carry in the compiled module (the device trace's op names)
 KERNELS = {
     "flash_fwd": (_flash_fwd, [((1, H, S, D), BF), ((1, KVH, S, D), BF),
-                               ((1, KVH, S, D), BF)]),
+                               ((1, KVH, S, D), BF)], {"flash_fwd"}),
     "flash_bwd": (_flash_bwd, [((1, H, S, D), BF), ((1, KVH, S, D), BF),
-                               ((1, KVH, S, D), BF), ((1, H, S, D), BF)]),
+                               ((1, KVH, S, D), BF), ((1, H, S, D), BF)],
+                  {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
     "flash_decode": (lambda *a: flash_decode(*a, interpret=False),
                      [((4, H, D), BF), ((4, KVH, T, D), BF),
-                      ((4, KVH, T, D), BF), ((4, T), I32), ((4,), I32)]),
+                      ((4, KVH, T, D), BF), ((4, T), I32), ((4,), I32)],
+                     {"decode_attn"}),
     # recurrentgemma-2b's RG-LRU width
     "rglru_scan": (lambda a, b: rglru_scan(a, b, interpret=False),
-                   [((2, S, 2560), F32), ((2, S, 2560), F32)]),
+                   [((2, S, 2560), F32), ((2, S, 2560), F32)],
+                   {"rglru_scan"}),
     # rwkv6-7b: 64 heads of 64
     "rwkv6_wkv": (lambda *a: rwkv6_wkv(*a, interpret=False),
-                  [((1, 64, S, 64), BF)] * 4 + [((64, 64), BF)]),
+                  [((1, 64, S, 64), BF)] * 4 + [((64, 64), BF)],
+                  {"rwkv6_scan"}),
 }
+CUSTOM_CALL = re.compile(r"%([\w.-]+?)(?:\.\d+)? = .*custom_call_target="
+                         r"\"tpu_custom_call\"")
+# under grad the instruction is named for the transforms around the
+# kernel too: transpose_jvp_flash_bwd_dq__
+TRANSFORMS = re.compile(r"^(?:(?:transpose|jvp|vmap)_)+|_+$")
 
 
 @pytest.mark.parametrize("name", sorted(KERNELS))
 def test_kernel_compiles_for_v5e(topo, name):
-    fn, shapes = KERNELS[name]
+    fn, shapes, kernel_names = KERNELS[name]
     one_chip = SingleDeviceSharding(topo.devices[0])
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    found = {TRANSFORMS.sub("", n)
+             for n in CUSTOM_CALL.findall(compiled.as_text())}
+    assert found == kernel_names
 
 
 def test_qwen2_prefill_cell_compiles_with_kernels(topo):

@@ -154,7 +154,7 @@ def phase_kernels(plan, seed):
     w = normal((1, H, S, D))
 
     def fa(q, k, v):
-        return flash_attention(q, k, v, None, 0, 128, 128, False)
+        return flash_attention(q, k, v, None, 0, None, None, False)
 
     def fa_loss(fn):
         return lambda q, k, v, w: (fn(q, k, v).astype(f32) * w).sum()

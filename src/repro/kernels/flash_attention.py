@@ -7,10 +7,12 @@ TPU adaptation notes (vs the CUDA flash-attention algorithm):
 * the KV loop is the *minor grid dimension* — TPU grids iterate the minor dim
   sequentially per core, so the (m, l, acc) online-softmax state lives in VMEM
   scratch that persists across KV iterations (no atomics / shared memory);
-* block shapes keep the MXU dims (block_q × D and block_k × D) multiples of
-  128 where the model dims allow;
+* a grid step has a fixed cost (its bookkeeping and block copies) of the
+  order of the work in a 128 x 128 block, so blocks are chosen from the
+  shapes (``choose_blocks``): a few hundred rows each, a multiple of 128;
 * fully-masked causal blocks are predicated off with ``pl.when`` rather than
-  skipped via grid surgery.
+  skipped via grid surgery, and their index maps repeat the nearest live
+  block's index, so Pallas copies nothing for them.
 
 Validated against ``ref.flash_attention_ref``, in interpret mode on the CPU
 and compiled by Mosaic on the chip.
@@ -27,6 +29,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -2.3819763e38
 
+TILE = 128                    # MXU width; blocks of longer sequences are multiples
+BLOCK_Q, BLOCK_K = 512, 1024  # the largest blocks chosen (v5e sweep, PERF.md)
+SCOPED_VMEM = 16 * 2**20      # Mosaic's default scoped VMEM limit (v5e)
+
 
 def _mm(a, b, contract):
     """Contract ``a`` with ``b`` on the MXU in their own dtype, accumulating
@@ -37,11 +43,116 @@ def _mm(a, b, contract):
                                preferred_element_type=jnp.float32)
 
 
+# ------------------------------------------------------------- block choice
+
+def _round_up(n, unit):
+    return -(-n // unit) * unit
+
+
+def _fit(length, target):
+    """The block that covers ``length`` in ceil(length / target) even pieces,
+    rounded up to the 128-row tile (to 16 rows for a shorter length)."""
+    n = -(-length // target)
+    return _round_up(-(-length // n), TILE if length > TILE else 16)
+
+
+def vmem_bytes(block_q, block_k, d, itemsize=2):
+    """VMEM the largest of the three kernels holds at these blocks: every
+    in and out block twice (double-buffered), the f32 accumulators, the f32
+    row statistics (one lane-padded column each), and six f32 (bq, bk)
+    temporaries (s, p, dp, ds and the mask's two position grids)."""
+    lanes = _round_up(d, TILE)
+    big = max(block_q, block_k)
+    blocks = 2 * itemsize * lanes * (2 * block_q + 2 * block_k + 2 * big)
+    stats = 2 * 2 * 4 * TILE * block_q
+    acc = 2 * 4 * lanes * big
+    return blocks + stats + acc + 6 * 4 * block_q * block_k
+
+
+def choose_blocks(sq, skv):
+    """``(block_q, block_k)`` for attention of ``sq`` queries over ``skv``
+    keys: up to ``BLOCK_Q`` x ``BLOCK_K``, split evenly over the lengths.
+    At head dims up to 256 the kernels then need under 32 MiB of VMEM
+    (``vmem_bytes``), a quarter of v5e's."""
+    return _fit(sq, BLOCK_Q), _fit(skv, BLOCK_K)
+
+
+def _blocks(sq, skv, block_q, block_k):
+    """The caller's blocks (each capped at its length), else the chosen."""
+    cq, ck = choose_blocks(sq, skv)
+    return (cq if block_q is None else min(block_q, sq),
+            ck if block_k is None else min(block_k, skv))
+
+
+def _compiler_params(bq, bk, d, itemsize):
+    """A scoped VMEM limit from the blocks, where the default is too small."""
+    need = vmem_bytes(bq, bk, d, itemsize)
+    if need <= SCOPED_VMEM:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=need + need // 4)
+
+
+# ------------------------------------------------------------ block geometry
+
+def live_k_range(qi, *, block_q, block_k, n_k, window, causal_shift):
+    """First and last KV block that query block ``qi`` attends to."""
+    r0 = qi * block_q + causal_shift            # absolute position, first row
+    last = jnp.minimum(jnp.maximum(r0 + block_q - 1, 0) // block_k, n_k - 1)
+    if window is None:
+        return 0, last
+    first = jnp.minimum(jnp.maximum(r0 - window + 1, 0) // block_k, n_k - 1)
+    return first, last
+
+
+def live_q_range(ki, *, block_q, block_k, n_q, window, causal_shift):
+    """First and last query block that attends to KV block ``ki``."""
+    c0 = ki * block_k
+    first = jnp.minimum(jnp.maximum(c0 - causal_shift, 0) // block_q, n_q - 1)
+    if window is None:
+        return first, n_q - 1
+    last = jnp.minimum(
+        jnp.maximum(c0 + block_k - 2 - causal_shift + window, 0) // block_q,
+        n_q - 1)
+    return first, last
+
+
+def _clamp(i, lo, hi):
+    return jnp.minimum(jnp.maximum(i, lo), hi)
+
+
+def _block_live(qi, ki, *, block_q, block_k, window, causal_shift, **_):
+    """Whether block (qi, ki) keeps any (query, key) pair under the causal
+    mask (and the window)."""
+    r0 = qi * block_q + causal_shift            # absolute position, first row
+    c0 = ki * block_k
+    live = c0 <= r0 + block_q - 1
+    if window is not None:
+        live &= c0 + block_k - 1 > r0 - window
+    return live
+
+
+def _mask(qi, ki, *, block_q, block_k, sq_valid, skv_valid, window,
+          causal_shift):
+    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
+    # causal_shift aligns q row i with absolute position i + causal_shift
+    q_abs = q_pos + causal_shift
+    mask = (k_pos <= q_abs) & (q_pos < sq_valid) & (k_pos < skv_valid)
+    if window is not None:
+        mask &= k_pos > q_abs - window
+    return mask
+
+
+def _scores(q, k, qi, ki, geom):
+    """Block (qi, ki)'s scaled scores, NEG_INF where the mask drops them."""
+    s = _mm(q, k, ((1,), (1,))) * (1.0 / np.sqrt(q.shape[-1]))
+    return jnp.where(_mask(qi, ki, **geom), s, NEG_INF)
+
+
 # ------------------------------------------------------------------- forward
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                *, block_q, block_k, n_kv_blocks, sq_valid, skv_valid,
-                window, causal_shift):
+                *, n_kv_blocks, **geom):
     """Grid: (B, H, nQ, nKV) — nKV minor (sequential)."""
     ki = pl.program_id(3)
     qi = pl.program_id(2)
@@ -52,24 +163,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    # causal_shift aligns q row i with absolute position i + causal_shift
-    q_abs = q_pos + causal_shift
-    mask = (k_pos <= q_abs) & (q_pos < sq_valid) & (k_pos < skv_valid)
-    if window is not None:
-        mask &= k_pos > q_abs - window
-
-    block_live = (ki * block_k <= qi * block_q + causal_shift + block_q - 1)
-    if window is not None:
-        block_live &= ((ki + 1) * block_k - 1
-                       > qi * block_q + causal_shift - window)
-
-    @pl.when(block_live)
+    @pl.when(_block_live(qi, ki, **geom))
     def _compute():
         q, k, v = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0]
-        s = _mm(q, k, ((1,), (1,))) * (1.0 / np.sqrt(q.shape[-1]))
-        s = jnp.where(mask, s, NEG_INF)
+        s = _scores(q, k, qi, ki, geom)
         m_prev = m_ref[...]                                 # (bq, 1)
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -86,33 +183,41 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         lse_ref[0, 0] = m_ref[...] + jnp.log(l)
 
 
+def _pad(x, n):
+    return jnp.pad(x, ((0, 0), (0, 0), (0, n - x.shape[2]), (0, 0)))
+
+
 def flash_attention_fwd(q, k, v, *, window=None, causal_shift=0,
-                        block_q=128, block_k=128, interpret=False):
-    """q: (B,H,Sq,D); k,v: (B,KVH,Skv,D). Returns (o, lse)."""
+                        block_q=None, block_k=None, interpret=False):
+    """q: (B,H,Sq,D); k,v: (B,KVH,Skv,D). Returns (o, lse).  Blocks left
+    ``None`` are chosen from the shapes (``choose_blocks``)."""
     B, H, Sq, D = q.shape
     KVH, Skv = k.shape[1], k.shape[2]
     G = H // KVH
-    bq, bk = min(block_q, Sq), min(block_k, Skv)
+    bq, bk = _blocks(Sq, Skv, block_q, block_k)
     nq, nk = -(-Sq // bq), -(-Skv // bk)
-    qp = jnp.pad(q, ((0, 0), (0, 0), (0, nq * bq - Sq), (0, 0)))
-    kp = jnp.pad(k, ((0, 0), (0, 0), (0, nk * bk - Skv), (0, 0)))
-    vp = jnp.pad(v, ((0, 0), (0, 0), (0, nk * bk - Skv), (0, 0)))
+    geom = dict(block_q=bq, block_k=bk, sq_valid=Sq, skv_valid=Skv,
+                window=window, causal_shift=causal_shift)
+    live_k = functools.partial(live_k_range, block_q=bq, block_k=bk, n_k=nk,
+                               window=window, causal_shift=causal_shift)
 
-    kernel = functools.partial(
-        _fwd_kernel, block_q=bq, block_k=bk, n_kv_blocks=nk,
-        sq_valid=Sq, skv_valid=Skv, window=window, causal_shift=causal_shift)
-    grid = (B, H, nq, nk)
+    def q_map(b, h, qi, ki):
+        return (b, h, qi, 0)
+
+    def kv_map(b, h, qi, ki):
+        return (b, h // G, _clamp(ki, *live_k(qi)), 0)
+
     o, lse = pl.pallas_call(
-        kernel,
-        grid=grid,
+        functools.partial(_fwd_kernel, n_kv_blocks=nk, **geom),
+        grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, qi, ki: (b, h // G, ki, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, qi, ki: (b, h // G, ki, 0)),
+            pl.BlockSpec((1, 1, bq, D), q_map),
+            pl.BlockSpec((1, 1, bk, D), kv_map),
+            pl.BlockSpec((1, 1, bk, D), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, bq, D), q_map),
+            pl.BlockSpec((1, 1, bq, 1), q_map),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, nq * bq, D), q.dtype),
@@ -123,17 +228,17 @@ def flash_attention_fwd(q, k, v, *, window=None, causal_shift=0,
             pltpu.VMEM((bq, 1), jnp.float32),   # m
             pltpu.VMEM((bq, 1), jnp.float32),   # l
         ],
+        compiler_params=_compiler_params(bq, bk, D, q.dtype.itemsize),
         interpret=interpret,
         name="flash_fwd",
-    )(qp, kp, vp)
+    )(_pad(q, nq * bq), _pad(k, nk * bk), _pad(v, nk * bk))
     return o[:, :, :Sq], lse[:, :, :Sq, 0]
 
 
 # ------------------------------------------------------------------ backward
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_acc, *, block_q, block_k, n_kv_blocks, sq_valid,
-                   skv_valid, window, causal_shift):
+                   dq_acc, *, n_kv_blocks, **geom):
     ki = pl.program_id(3)
     qi = pl.program_id(2)
 
@@ -141,28 +246,13 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    q_abs = q_pos + causal_shift
-    mask = (k_pos <= q_abs) & (q_pos < sq_valid) & (k_pos < skv_valid)
-    if window is not None:
-        mask &= k_pos > q_abs - window
-    block_live = (ki * block_k <= qi * block_q + causal_shift + block_q - 1)
-    if window is not None:
-        block_live &= ((ki + 1) * block_k - 1
-                       > qi * block_q + causal_shift - window)
-
-    @pl.when(block_live)
+    @pl.when(_block_live(qi, ki, **geom))
     def _compute():
         q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
         scale = 1.0 / np.sqrt(q.shape[-1])
-        s = _mm(q, k, ((1,), (1,))) * scale
-        s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse)
+        p = jnp.exp(_scores(q, k, qi, ki, geom) - lse_ref[0, 0])
         dp = _mm(do, v, ((1,), (1,)))
-        ds = p * (dp - delta) * scale
+        ds = p * (dp - delta_ref[0, 0]) * scale
         dq_acc[...] += _mm(ds.astype(k.dtype), k, ((1,), (0,)))
 
     @pl.when(ki == n_kv_blocks - 1)
@@ -171,8 +261,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, dk_acc, dv_acc, *, block_q, block_k,
-                    n_q_blocks, n_g, sq_valid, skv_valid, window, causal_shift):
+                    dk_ref, dv_ref, dk_acc, dv_acc, *, n_q_blocks, n_g,
+                    **geom):
     """Grid: (B, KVH, nK, G*nQ) — inner loop over (g, qi) accumulates dk/dv."""
     inner = pl.program_id(3)
     ki = pl.program_id(2)
@@ -183,29 +273,14 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    q_pos = qi * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-    k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-    q_abs = q_pos + causal_shift
-    mask = (k_pos <= q_abs) & (q_pos < sq_valid) & (k_pos < skv_valid)
-    if window is not None:
-        mask &= k_pos > q_abs - window
-    block_live = (ki * block_k <= qi * block_q + causal_shift + block_q - 1)
-    if window is not None:
-        block_live &= ((ki + 1) * block_k - 1
-                       > qi * block_q + causal_shift - window)
-
-    @pl.when(block_live)
+    @pl.when(_block_live(qi, ki, **geom))
     def _compute():
         q, k, v, do = q_ref[0, 0], k_ref[0, 0], v_ref[0, 0], do_ref[0, 0]
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
         scale = 1.0 / np.sqrt(q.shape[-1])
-        s = _mm(q, k, ((1,), (1,))) * scale
-        s = jnp.where(mask, s, NEG_INF)
-        p = jnp.exp(s - lse)                                # (bq, bk)
+        p = jnp.exp(_scores(q, k, qi, ki, geom) - lse_ref[0, 0])   # (bq, bk)
         dv_acc[...] += _mm(p.astype(do.dtype), do, ((0,), (0,)))
         dp = _mm(do, v, ((1,), (1,)))
-        ds = p * (dp - delta) * scale                       # (bq, bk)
+        ds = p * (dp - delta_ref[0, 0]) * scale                   # (bq, bk)
         dk_acc[...] += _mm(ds.astype(q.dtype), q, ((0,), (0,)))
 
     @pl.when(inner == n_g * n_q_blocks - 1)
@@ -215,62 +290,72 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def flash_attention_bwd(q, k, v, o, lse, do, *, window=None, causal_shift=0,
-                        block_q=128, block_k=128, interpret=False):
+                        block_q=None, block_k=None, interpret=False):
     B, H, Sq, D = q.shape
     KVH, Skv = k.shape[1], k.shape[2]
     G = H // KVH
-    bq, bk = min(block_q, Sq), min(block_k, Skv)
+    bq, bk = _blocks(Sq, Skv, block_q, block_k)
     nq, nk = -(-Sq // bq), -(-Skv // bk)
-    pad_q = nq * bq - Sq
-    pad_k = nk * bk - Skv
-    qp = jnp.pad(q, ((0, 0), (0, 0), (0, pad_q), (0, 0)))
-    kp = jnp.pad(k, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
-    vp = jnp.pad(v, ((0, 0), (0, 0), (0, pad_k), (0, 0)))
-    dop = jnp.pad(do, ((0, 0), (0, 0), (0, pad_q), (0, 0)))
+    qp, dop = _pad(q, nq * bq), _pad(do, nq * bq)
+    kp, vp = _pad(k, nk * bk), _pad(v, nk * bk)
     # row statistics travel as (.., S, 1) columns: a block's last two dims
     # must tile (8, 128) or span the array, and a 1-wide last dim spans it
-    lsep = jnp.pad(lse, ((0, 0), (0, 0), (0, pad_q)))[..., None]
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    deltap = jnp.pad(delta, ((0, 0), (0, 0), (0, pad_q)))[..., None]
+    lsep, deltap = (_pad(x[..., None], nq * bq) for x in (lse, delta))
 
-    common = dict(block_q=bq, block_k=bk, sq_valid=Sq, skv_valid=Skv,
-                  window=window, causal_shift=causal_shift)
+    geom = dict(block_q=bq, block_k=bk, sq_valid=Sq, skv_valid=Skv,
+                window=window, causal_shift=causal_shift)
+    params = _compiler_params(bq, bk, D, q.dtype.itemsize)
+    live_k = functools.partial(live_k_range, block_q=bq, block_k=bk, n_k=nk,
+                               window=window, causal_shift=causal_shift)
+    live_q = functools.partial(live_q_range, block_q=bq, block_k=bk, n_q=nq,
+                               window=window, causal_shift=causal_shift)
+
+    def row_map(b, h, qi, ki):
+        return (b, h, qi, 0)
+
+    def kv_map(b, h, qi, ki):
+        return (b, h // G, _clamp(ki, *live_k(qi)), 0)
+
     dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel, n_kv_blocks=nk, **common),
+        functools.partial(_bwd_dq_kernel, n_kv_blocks=nk, **geom),
         grid=(B, H, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, qi, ki: (b, h // G, ki, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, h, qi, ki: (b, h // G, ki, 0)),
-            pl.BlockSpec((1, 1, bq, D), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda b, h, qi, ki: (b, h, qi, 0)),
-            pl.BlockSpec((1, 1, bq, 1), lambda b, h, qi, ki: (b, h, qi, 0)),
+            pl.BlockSpec((1, 1, bq, D), row_map),
+            pl.BlockSpec((1, 1, bk, D), kv_map),
+            pl.BlockSpec((1, 1, bk, D), kv_map),
+            pl.BlockSpec((1, 1, bq, D), row_map),
+            pl.BlockSpec((1, 1, bq, 1), row_map),
+            pl.BlockSpec((1, 1, bq, 1), row_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, qi, ki: (b, h, qi, 0)),
+        out_specs=pl.BlockSpec((1, 1, bq, D), row_map),
         out_shape=jax.ShapeDtypeStruct((B, H, nq * bq, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        compiler_params=params,
         interpret=interpret,
         name="flash_bwd_dq",
     )(qp, kp, vp, dop, lsep, deltap)
 
-    def _q_map(b, kh, ki, i):
-        return (b, kh * G + i // nq, i % nq, 0)
+    def q_map(b, kh, ki, i):
+        return (b, kh * G + i // nq, _clamp(i % nq, *live_q(ki)), 0)
 
+    def own_map(b, kh, ki, i):
+        return (b, kh, ki, 0)
 
     dk, dv = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel, n_q_blocks=nq, n_g=G, **common),
+        functools.partial(_bwd_dkv_kernel, n_q_blocks=nq, n_g=G, **geom),
         grid=(B, KVH, nk, G * nq),
         in_specs=[
-            pl.BlockSpec((1, 1, bq, D), _q_map),
-            pl.BlockSpec((1, 1, bk, D), lambda b, kh, ki, i: (b, kh, ki, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, kh, ki, i: (b, kh, ki, 0)),
-            pl.BlockSpec((1, 1, bq, D), _q_map),
-            pl.BlockSpec((1, 1, bq, 1), _q_map),
-            pl.BlockSpec((1, 1, bq, 1), _q_map),
+            pl.BlockSpec((1, 1, bq, D), q_map),
+            pl.BlockSpec((1, 1, bk, D), own_map),
+            pl.BlockSpec((1, 1, bk, D), own_map),
+            pl.BlockSpec((1, 1, bq, D), q_map),
+            pl.BlockSpec((1, 1, bq, 1), q_map),
+            pl.BlockSpec((1, 1, bq, 1), q_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, 1, bk, D), lambda b, kh, ki, i: (b, kh, ki, 0)),
-            pl.BlockSpec((1, 1, bk, D), lambda b, kh, ki, i: (b, kh, ki, 0)),
+            pl.BlockSpec((1, 1, bk, D), own_map),
+            pl.BlockSpec((1, 1, bk, D), own_map),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, KVH, nk * bk, D), k.dtype),
@@ -278,6 +363,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, window=None, causal_shift=0,
         ],
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
+        compiler_params=params,
         interpret=interpret,
         name="flash_bwd_dkv",
     )(qp, kp, vp, dop, lsep, deltap)
@@ -287,8 +373,8 @@ def flash_attention_bwd(q, k, v, o, lse, do, *, window=None, causal_shift=0,
 # ------------------------------------------------------- custom_vjp assembly
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def flash_attention(q, k, v, window=None, causal_shift=0, block_q=128,
-                    block_k=128, interpret=False):
+def flash_attention(q, k, v, window=None, causal_shift=0, block_q=None,
+                    block_k=None, interpret=False):
     o, _ = flash_attention_fwd(q, k, v, window=window,
                                causal_shift=causal_shift, block_q=block_q,
                                block_k=block_k, interpret=interpret)

@@ -23,7 +23,9 @@ from .rwkv6_kernel import rwkv6_wkv as _rwkv6_wkv
                                              "block_q", "block_k",
                                              "interpret"))
 def attention(q, k, v, *, window=None, use_pallas=True,
-              block_q=128, block_k=128, interpret=False):
+              block_q=None, block_k=None, interpret=False):
+    """Causal attention; the kernel's blocks, left ``None``, are chosen from
+    the shapes (``flash_attention.choose_blocks``)."""
     if use_pallas:
         return _flash_attention(q, k, v, window, 0, block_q, block_k,
                                 interpret)

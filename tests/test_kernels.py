@@ -4,8 +4,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro import hw
 from repro.kernels import ref
 from repro.kernels.decode_attention import flash_decode
+from repro.kernels import flash_attention as fa
 from repro.kernels.flash_attention import flash_attention, flash_attention_fwd
 from repro.kernels.rglru_scan import rglru_scan
 from repro.kernels.rwkv6_kernel import rwkv6_wkv
@@ -58,6 +60,161 @@ def test_flash_attention_grads(window):
     gr = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
     for a, b, name in zip(gk, gr, "qkv"):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5,
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("S", [128, 256, 384, 512, 1000, 1024, 1100, 1536,
+                               2048, 33, 100, 2047])
+def test_choose_blocks(S):
+    """Blocks tile the length evenly in 128-row multiples (16 below 128),
+    as few as the largest blocks allow, padding by less than a tile a
+    block; at every head dim the models use (up to 256) the kernels' VMEM
+    stays within a quarter of the chip's."""
+    for sq, skv in ((S, S), (S, 2048), (2048, S)):
+        bq, bk = fa.choose_blocks(sq, skv)
+        for b, n, most in ((bq, sq, fa.BLOCK_Q), (bk, skv, fa.BLOCK_K)):
+            unit = 128 if n > 128 else 16
+            assert b % unit == 0 and 0 < b <= max(most, unit)
+            blocks = -(-n // b)
+            assert blocks == -(-n // most)
+            assert blocks * b - n < blocks * unit
+        for d in (64, 128, 256):
+            assert fa.vmem_bytes(bq, bk, d, 4) <= hw.V5E.vmem_bytes / 4
+
+
+def test_choose_blocks_cells():
+    """The benchmark cells' shapes (qwen2-1.5b's 2048 prefill, internvl2-1b's
+    2048 training rows) take the largest blocks; shorter and ragged lengths
+    split evenly."""
+    assert fa.choose_blocks(2048, 2048) == (fa.BLOCK_Q, fa.BLOCK_K) == (512, 1024)
+    assert fa.choose_blocks(128, 128) == (128, 128)
+    assert fa.choose_blocks(1536, 1536) == (512, 768)
+    assert fa.choose_blocks(1100, 1300) == (384, 768)
+
+
+def test_vmem_limit_follows_the_blocks():
+    """A scoped VMEM limit is asked for only past Mosaic's default, and then
+    from the blocks' own need."""
+    assert fa._compiler_params(128, 128, 128, 2) is None
+    need = fa.vmem_bytes(fa.BLOCK_Q, fa.BLOCK_K, 128)
+    assert need > fa.SCOPED_VMEM
+    limit = fa._compiler_params(fa.BLOCK_Q, fa.BLOCK_K, 128, 2).vmem_limit_bytes
+    assert need <= limit <= 2 * need
+
+
+def _fetches(indexes):
+    """Block copies a grid makes: one each time the index changes."""
+    return [b for i, b in enumerate(indexes) if i == 0 or b != indexes[i - 1]]
+
+
+GEOMS = [  # Sq, Skv, bq, bk, window, causal_shift
+    (2048, 2048, 1024, 512, None, 0),
+    (2048, 2048, 512, 512, None, 0),
+    (1536, 1536, 512, 512, 600, 0),
+    (1100, 1300, 256, 512, 300, 200),
+    (48, 48, 16, 16, 24, 0),
+    (33, 65, 16, 16, None, 32),
+]
+
+
+@pytest.mark.parametrize("sq,skv,bq,bk,window,shift",
+                         GEOMS + [(40, 40, 16, 16, 7, 1), (37, 53, 8, 16, 5, 3),
+                                  (40, 54, 16, 16, None, 14)])
+def test_live_blocks_match_mask(sq, skv, bq, bk, window, shift):
+    """A block is live exactly where the causal (and window) mask keeps a
+    pair, and the kernels' mask drops every pair outside it and the
+    padding."""
+    nq, nk = -(-sq // bq), -(-skv // bk)
+    geom = dict(block_q=bq, block_k=bk, sq_valid=sq, skv_valid=skv,
+                window=window, causal_shift=shift)
+    for qi in range(nq):
+        for ki in range(nk):
+            q = (qi * bq + np.arange(bq))[:, None]
+            k = (ki * bk + np.arange(bk))[None, :]
+            keep = k <= q + shift
+            if window is not None:
+                keep &= k > q + shift - window
+            assert bool(fa._block_live(qi, ki, **geom)) == keep.any()
+            np.testing.assert_array_equal(np.asarray(fa._mask(qi, ki, **geom)),
+                                          keep & (q < sq) & (k < skv))
+
+
+@pytest.mark.parametrize("sq,skv,bq,bk,window,shift", GEOMS)
+def test_clamped_index_maps(sq, skv, bq, bk, window, shift):
+    """Dead causal blocks map to the nearest live block's index, so the
+    grid copies each live block once and nothing for the dead ones; live
+    blocks keep their own index."""
+    nq, nk = -(-sq // bq), -(-skv // bk)
+    geom = dict(block_q=bq, block_k=bk, sq_valid=sq, skv_valid=skv,
+                window=window, causal_shift=shift)
+
+    def live(qi, ki):
+        return bool(fa._block_live(qi, ki, **geom))
+
+    # forward and dq: K/V blocks along ki for each qi
+    kv, kv_live = [], []
+    for qi in range(nq):
+        first, last = (int(x) for x in fa.live_k_range(
+            qi, block_q=bq, block_k=bk, n_k=nk, window=window,
+            causal_shift=shift))
+        for ki in range(nk):
+            got = int(fa._clamp(ki, first, last))
+            assert got == ki if live(qi, ki) else got in (first, last)
+            if ki > last:
+                assert got == last
+            kv.append((qi, got))
+            if live(qi, ki):
+                kv_live.append((qi, ki))
+    assert [b for _, b in _fetches(kv)] == [b for _, b in _fetches(kv_live)]
+    # dk/dv: Q/dO/lse/delta blocks along qi for each ki (one head)
+    qs, q_live = [], []
+    for ki in range(nk):
+        first, last = (int(x) for x in fa.live_q_range(
+            ki, block_q=bq, block_k=bk, n_q=nq, window=window,
+            causal_shift=shift))
+        for qi in range(nq):
+            got = int(fa._clamp(qi, first, last))
+            assert got == qi if live(qi, ki) else got in (first, last)
+            if qi < first:
+                assert got == first
+            qs.append((ki, got))
+            if live(qi, ki):
+                q_live.append((ki, qi))
+    assert [b for _, b in _fetches(qs)] == [b for _, b in _fetches(q_live)]
+
+
+@pytest.mark.parametrize("B,H,KVH,Sq,Skv,D,window", [
+    (1, 6, 1, 1536, 1536, 64, None),     # GQA 6:1, 3 x 2 blocks of 512 x 768
+    (1, 7, 1, 1100, 1300, 128, 300),     # GQA 7:1, ragged, window, shift
+    (1, 6, 1, 1280, 1280, 128, 500),     # window across blocks
+])
+def test_flash_attention_default_blocks(B, H, KVH, Sq, Skv, D, window):
+    """Forward and gradients at the chosen blocks, over several of them,
+    against the reference."""
+    key = jax.random.PRNGKey(11)
+    q = rand(key, (B, H, Sq, D), jnp.float32)
+    k = rand(jax.random.fold_in(key, 1), (B, KVH, Skv, D), jnp.float32)
+    v = rand(jax.random.fold_in(key, 2), (B, KVH, Skv, D), jnp.float32)
+    w = rand(jax.random.fold_in(key, 3), (B, H, Sq, D), jnp.float32)
+    shift = Skv - Sq
+    bq, bk = fa.choose_blocks(Sq, Skv)
+    assert -(-Sq // bq) > 1 and -(-Skv // bk) > 1
+
+    def f_ker(q, k, v):
+        return (flash_attention(q, k, v, window, shift, None, None, True)
+                * w).sum()
+
+    def f_ref(q, k, v):
+        return (ref.flash_attention_ref(q, k, v, window=window,
+                                        causal_shift=shift) * w).sum()
+
+    o = flash_attention(q, k, v, window, shift, None, None, True)
+    r = ref.flash_attention_ref(q, k, v, window=window, causal_shift=shift)
+    np.testing.assert_allclose(np.asarray(o), np.asarray(r), atol=2e-5)
+    gk = jax.grad(f_ker, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(f_ref, argnums=(0, 1, 2))(q, k, v)
+    for a, b, name in zip(gk, gr, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4,
                                    err_msg=f"d{name}")
 
 

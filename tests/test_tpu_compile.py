@@ -1,5 +1,6 @@
 """Compiles for a described TPU v5e, without a chip: the Pallas kernels at the
-widths the chip smoke runs them, and one qwen2-1.5b prefill cell on the
+widths the chip smoke runs them (the flash kernels at their chosen blocks,
+at both benchmark cells' shapes), and one qwen2-1.5b prefill cell on the
 kernel path.  Mosaic refuses here what interpret mode accepts: block shapes
 off the (8, 128) tiling, more VMEM than a kernel may use, primitives it
 cannot lower.  Nothing runs, so these say nothing about results or times.
@@ -48,7 +49,7 @@ def topo():
 
 
 def _flash_fwd(q, k, v):
-    return flash_attention(q, k, v, None, 0, 128, 128, False)
+    return flash_attention(q, k, v, None, 0, None, None, False)
 
 
 def _flash_bwd(q, k, v, w):
@@ -59,6 +60,8 @@ def _flash_bwd(q, k, v, w):
 
 H, KVH, D, S, T = 12, 2, 128, 2048, 2048     # qwen2-1.5b heads, S, cache
 BF, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+# internvl2-1b's training: batch 2, 14 query and 2 KV heads of 64
+VB, VH, VD = 2, 14, 64
 
 # each entry: the function, its argument shapes, and the names its Pallas
 # kernels carry in the compiled module (the device trace's op names)
@@ -68,6 +71,15 @@ KERNELS = {
     "flash_bwd": (_flash_bwd, [((1, H, S, D), BF), ((1, KVH, S, D), BF),
                                ((1, KVH, S, D), BF), ((1, H, S, D), BF)],
                   {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
+    "flash_fwd_internvl2": (_flash_fwd, [((VB, VH, S, VD), BF),
+                                         ((VB, KVH, S, VD), BF),
+                                         ((VB, KVH, S, VD), BF)],
+                            {"flash_fwd"}),
+    "flash_bwd_internvl2": (_flash_bwd, [((VB, VH, S, VD), BF),
+                                         ((VB, KVH, S, VD), BF),
+                                         ((VB, KVH, S, VD), BF),
+                                         ((VB, VH, S, VD), BF)],
+                            {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}),
     "flash_decode": (lambda *a: flash_decode(*a, interpret=False),
                      [((4, H, D), BF), ((4, KVH, T, D), BF),
                       ((4, KVH, T, D), BF), ((4, T), I32), ((4,), I32)],

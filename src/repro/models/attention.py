@@ -166,14 +166,6 @@ def local_chunk_attention(q, k, v, positions_q, positions_kv, window):
     return out[:, :S]
 
 
-def init_cache(batch, cache_len, n_kv, d_head, dtype):
-    return {
-        "k": jnp.zeros((batch, cache_len, n_kv, d_head), dtype),
-        "v": jnp.zeros((batch, cache_len, n_kv, d_head), dtype),
-        "pos": jnp.full((batch, cache_len), -1, jnp.int32),
-    }
-
-
 def cache_shapes(batch, cache_len, n_kv, d_head, dtype):
     return {
         "k": jax.ShapeDtypeStruct((batch, cache_len, n_kv, d_head), dtype),
@@ -187,6 +179,24 @@ CACHE_AXES = {"k": ("batch", "cache_seq", "kv_heads", "head_dim"),
               "pos": ("batch", "cache_seq")}
 
 
+def _write_rows(cache, slot, rows):
+    """Each lane's new key, value and position into its one row
+    ``(b, slot[b])`` of the cache.  With the state donated the scatter
+    writes the cache in place, and attention reads the same buffer; under
+    cache_seq sharding each shard writes the rows that fall in it."""
+    lane = jnp.arange(slot.shape[0])
+    if slot.shape[0] == 1:
+        # XLA rewrites a scatter of one row as a dynamic-update-slice, which
+        # the partitioner applies to the whole gathered cache when its
+        # sequence axis is sharded; a scatter of the row twice stays a
+        # scatter, on the shard that holds the row
+        lane = jnp.concatenate([lane, lane])
+        slot = jnp.concatenate([slot, slot])
+        rows = {n: jnp.concatenate([r, r]) for n, r in rows.items()}
+    return {n: cache[n].at[lane, slot].set(rows[n].astype(cache[n].dtype))
+            for n in cache}
+
+
 def decode_attention(p, cache, x, position, *, n_heads, n_kv, d_head,
                      rope_theta, window=None, use_rope=True):
     """One-token decode. x: (B,1,D); position: (B,) int32 current index.
@@ -195,24 +205,14 @@ def decode_attention(p, cache, x, position, *, n_heads, n_kv, d_head,
     linear buffer (slot = pos).  K is stored post-RoPE.
     Returns (attn_out (B,1,KV,G,dh), new_cache).
     """
-    B = x.shape[0]
     T = cache["k"].shape[1]
     q, k, v = qkv_proj(p, x, n_heads, n_kv, d_head,
                        position[:, None], rope_theta, use_rope)
     slot = position % T if window is not None else jnp.minimum(position, T - 1)
 
-    # masked-where write: elementwise over the cache slice, so it partitions
-    # cleanly under cache_seq sharding (a scatter forces gather/select
-    # plumbing; see EXPERIMENTS.md §Perf deepseek decode iteration 4)
     with jax.named_scope("kv_cache"):
-        hit = (jnp.arange(T, dtype=jnp.int32)[None, :] == slot[:, None])
-        new_cache = {
-            "k": jnp.where(hit[..., None, None], k.astype(cache["k"].dtype),
-                           cache["k"]),
-            "v": jnp.where(hit[..., None, None], v.astype(cache["v"].dtype),
-                           cache["v"]),
-            "pos": jnp.where(hit, position[:, None], cache["pos"]),
-        }
+        new_cache = _write_rows(cache, slot, {"k": k[:, 0], "v": v[:, 0],
+                                              "pos": position})
     kk, vv, pos_kv = new_cache["k"], new_cache["v"], new_cache["pos"]
     g = n_heads // n_kv
     q = maybe_constrain(q, ("batch", None, "kv_heads", "heads", "head_dim"))
@@ -262,30 +262,3 @@ def full_attention(p, x, positions, *, n_heads, n_kv, d_head, rope_theta,
         o = plain_attention(q, k, v, positions, positions, window)
     o = maybe_constrain(o, ("batch", "seq_q", "kv_heads", "heads", "head_dim"))
     return out_proj(p, o)
-
-
-def prefill_cache_from_kv(p, x, positions, *, n_heads, n_kv, d_head, rope_theta,
-                          cache_len, window=None, use_rope=True):
-    """Recompute K,V (post-RoPE) for writing the prefill cache."""
-    _, k, v = qkv_proj(p, x, n_heads, n_kv, d_head, positions, rope_theta, use_rope)
-    S = x.shape[1]
-    if window is not None and cache_len < S:
-        # keep last ``cache_len`` tokens, ring-indexed by position
-        k, v = k[:, -cache_len:], v[:, -cache_len:]
-        pos = positions[:, -cache_len:]
-        slot = pos % cache_len
-        ck = jnp.zeros((x.shape[0], cache_len) + k.shape[2:], k.dtype)
-        cv = jnp.zeros_like(ck)
-        cp = jnp.full((x.shape[0], cache_len), -1, jnp.int32)
-        bidx = jnp.arange(x.shape[0])[:, None]
-        ck = ck.at[bidx, slot].set(k)
-        cv = cv.at[bidx, slot].set(v)
-        cp = cp.at[bidx, slot].set(pos)
-        return {"k": ck, "v": cv, "pos": cp}
-    pad = cache_len - S
-    if pad < 0:
-        raise ValueError("cache_len < seq_len for linear cache")
-    k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
-    pos = jnp.pad(positions, ((0, 0), (0, pad)), constant_values=-1)
-    return {"k": k, "v": v, "pos": pos}

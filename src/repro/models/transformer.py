@@ -4,8 +4,10 @@ Supports all 10 assigned architectures through ``ModelConfig``:
 dense/MoE GQA attention blocks, RG-LRU recurrent blocks, RWKV6 blocks,
 VLM patch-prefix and multi-codebook audio frontends.  Layers are grouped
 into repeating *pattern units* (e.g. ("rec","rec","attn") for
-recurrentgemma); units are either scanned (stacked params, production
-default) or unrolled (D3 search factor).
+recurrentgemma); in training and prefill, units are either scanned
+(stacked params, production default) or unrolled (D3 search factor).  The
+decode state is one tree per layer, and decode always unrolls the layers,
+so that each layer's cache is a buffer of its own, written in place.
 
 Named scopes mark the layers in the compiled programs' metadata, and so in
 the device trace: ``embed``, ``layers`` (the unit loop or scan, and the
@@ -390,34 +392,31 @@ def block_state_axes(bt: str):
             "rwkv": rwkv.RWKV_STATE_AXES}[bt]
 
 
-def _stack_shapes(tree, n):
-    return jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct((n,) + s.shape, s.dtype), tree)
+def layer_types(cfg: ModelConfig):
+    """The block type of every layer, in layer order."""
+    plen = len(cfg.block_pattern)
+    return [cfg.block_pattern[i % plen] for i in range(cfg.n_layers)]
+
+
+def layer_params(params, cfg: ModelConfig):
+    """Every layer's parameters, in layer order: the stacked units' slices,
+    then the tail."""
+    n_units, tail = n_units_tail(cfg)
+    plen = len(cfg.block_pattern)
+    out = [jax.tree.map(lambda a: a[u], params["units"][f"b{i}"])
+           for u in range(n_units) for i in range(plen)]
+    return out + [params["tail"][f"t{i}"] for i in range(tail)]
 
 
 def model_state_shapes(cfg: ModelConfig, batch: int, cache_len: int, dtype):
-    n_units, tail = n_units_tail(cfg)
-    unit = {f"b{i}": block_state_shapes(cfg, bt, batch, cache_len, dtype)
-            for i, bt in enumerate(cfg.block_pattern)}
-    out = {"units": _stack_shapes(unit, n_units)}
-    if tail:
-        out["tail"] = {f"t{i}": block_state_shapes(cfg, cfg.block_pattern[i],
-                                                   batch, cache_len, dtype)
-                       for i in range(tail)}
-    return out
+    """The decode state: one tree per layer, in layer order, each leaf
+    ``(batch, ...)``, so that each layer's cache is a buffer of its own."""
+    return [block_state_shapes(cfg, bt, batch, cache_len, dtype)
+            for bt in layer_types(cfg)]
 
 
 def model_state_axes(cfg: ModelConfig):
-    n_units, tail = n_units_tail(cfg)
-    unit = {f"b{i}": dict(block_state_axes(bt))
-            for i, bt in enumerate(cfg.block_pattern)}
-    stacked = jax.tree.map(lambda a: ("layers",) + tuple(a), unit,
-                           is_leaf=lambda a: isinstance(a, tuple))
-    out = {"units": stacked}
-    if tail:
-        out["tail"] = {f"t{i}": dict(block_state_axes(cfg.block_pattern[i]))
-                       for i in range(tail)}
-    return out
+    return [dict(block_state_axes(bt)) for bt in layer_types(cfg)]
 
 
 # --------------------------------------------------------------- full forward
@@ -448,17 +447,18 @@ def forward(params, batch, cfg: ModelConfig, policy: RunPolicy,
 
     def unit_fn(x, unit_params, positions):
         aux = jnp.zeros((2,), jnp.float32)
-        states = {}
+        states = []
         for i, bt in enumerate(pattern):
             x, a, st = apply_block_full(bt, unit_params[f"b{i}"], x, positions,
                                         cfg, policy, cache_len=cl)
             aux = aux + a
             if cl is not None:
-                states[f"b{i}"] = st
+                states.append(st)
         return x, aux, states
 
     unit_fn_r = _remat_wrap(unit_fn, policy)
 
+    layer_states = []
     with jax.named_scope("layers"):
         if policy.scan_layers and n_units > 1:
             def scan_body(carry, unit_params):
@@ -467,35 +467,31 @@ def forward(params, batch, cfg: ModelConfig, policy: RunPolicy,
                 return (x, acc + aux), states
             (x, aux), states = jax.lax.scan(
                 scan_body, (x, jnp.zeros((2,), jnp.float32)), cparams["units"])
+            # the decode state's layout: one tree per layer
+            layer_states = [jax.tree.map(lambda a: a[u], st)
+                            for u in range(n_units) for st in states]
         else:
             aux = jnp.zeros((2,), jnp.float32)
-            states_list = []
             for u in range(n_units):
                 up = jax.tree.map(lambda a: a[u], cparams["units"])
-                x, a, st = unit_fn_r(x, up, positions)
+                x, a, states = unit_fn_r(x, up, positions)
                 aux = aux + a
-                states_list.append(st)
-            states = jax.tree.map(lambda *xs: jnp.stack(xs), *states_list) \
-                if (cl is not None and states_list) else None
+                layer_states += states
 
-        tail_states = {}
         for i in range(tail):
             bt = pattern[i]
             x, a, st = apply_block_full(bt, cparams["tail"][f"t{i}"], x,
                                         positions, cfg, policy, cache_len=cl)
             aux = aux + a
             if cl is not None:
-                tail_states[f"t{i}"] = st
+                layer_states.append(st)
 
     with jax.named_scope("final_norm"):
         x = apply_norm(cparams["final_norm"], x, cfg.norm)
     if return_cache:
         last = x[:, -1]
         logits = unembed_logits(cparams, cfg, last)
-        state = {"units": states}
-        if tail:
-            state["tail"] = tail_states
-        return logits, aux, state
+        return logits, aux, layer_states
     logits = unembed_logits(cparams, cfg, x)
     return logits, aux
 
@@ -510,45 +506,15 @@ def decode_step(params, state, batch, cfg: ModelConfig, policy: RunPolicy):
                            if a.dtype == jnp.float32 else a, params)
     x, _ = embed_tokens(cparams, cfg, batch, compute_dtype)
     position = batch["position"]
-    pattern = cfg.block_pattern
-    n_units, tail = n_units_tail(cfg)
-
-    def unit_fn(x, unit_params, unit_state):
-        new_states = {}
-        for i, bt in enumerate(pattern):
-            x, st = apply_block_decode(bt, unit_params[f"b{i}"], unit_state[f"b{i}"],
-                                       x, position, cfg, policy)
-            new_states[f"b{i}"] = st
-        return x, new_states
-
+    new_state = []
+    # the layers unrolled, whatever policy.scan_layers says: a scan would
+    # slice each layer's cache out of a stack and write it back, where each
+    # layer here reads and writes its own buffer in place
     with jax.named_scope("layers"):
-        if policy.scan_layers and n_units > 1:
-            def scan_body(x, inp):
-                unit_params, unit_state = inp
-                x, ns = unit_fn(x, unit_params, unit_state)
-                return x, ns
-            x, new_unit_states = jax.lax.scan(
-                scan_body, x, (cparams["units"], state["units"]))
-        else:
-            ns_list = []
-            for u in range(n_units):
-                up = jax.tree.map(lambda a: a[u], cparams["units"])
-                us = jax.tree.map(lambda a: a[u], state["units"])
-                x, ns = unit_fn(x, up, us)
-                ns_list.append(ns)
-            new_unit_states = jax.tree.map(lambda *xs: jnp.stack(xs),
-                                           *ns_list)
-
-        new_state = {"units": new_unit_states}
-        if tail:
-            new_tail = {}
-            for i in range(tail):
-                bt = pattern[i]
-                x, st = apply_block_decode(bt, cparams["tail"][f"t{i}"],
-                                           state["tail"][f"t{i}"], x,
-                                           position, cfg, policy)
-                new_tail[f"t{i}"] = st
-            new_state["tail"] = new_tail
+        for bt, p, st in zip(layer_types(cfg), layer_params(cparams, cfg),
+                             state, strict=True):
+            x, st = apply_block_decode(bt, p, st, x, position, cfg, policy)
+            new_state.append(st)
 
     with jax.named_scope("final_norm"):
         x = apply_norm(cparams["final_norm"], x, cfg.norm)

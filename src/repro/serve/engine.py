@@ -46,22 +46,16 @@ class Request:
     logits: list | None = None  # a list here collects each token's logits
 
 
-def _update_slot(state, state1, slot: int):
+def _update_slot(state, state1, slot):
     """Write single-request state1 (batch 1) into lane ``slot`` of state.
 
-    State trees are {"units": leaves (n_units, B, ...), "tail": leaves (B, ...)}.
+    Both are one tree per layer (``models/transformer.model_state_shapes``),
+    every leaf ``(B, ...)``.
     """
-    out = {}
-    out["units"] = jax.tree.map(
+    return jax.tree.map(
         lambda dst, src: jax.lax.dynamic_update_slice_in_dim(
-            dst, src.astype(dst.dtype), slot, axis=1),
-        state["units"], state1["units"])
-    if "tail" in state:
-        out["tail"] = jax.tree.map(
-            lambda dst, src: jax.lax.dynamic_update_slice_in_dim(
-                dst, src.astype(dst.dtype), slot, axis=0),
-            state["tail"], state1["tail"])
-    return out
+            dst, src.astype(dst.dtype), slot, axis=0),
+        state, state1)
 
 
 class ServingEngine:
@@ -75,8 +69,10 @@ class ServingEngine:
         self.temperature = temperature
         self.key = jax.random.PRNGKey(seed)
         self.prefill = jax.jit(make_prefill_step(cfg, policy, cache_len))
-        self.decode = jax.jit(make_decode_step(cfg, policy))
-        self._update = jax.jit(_update_slot, static_argnums=2)
+        # the state donated: decode writes each layer's cache in place
+        self.decode = jax.jit(make_decode_step(cfg, policy), donate_argnums=1)
+        # the slot traced: one program writes any lane
+        self._update = jax.jit(_update_slot)
         self.state = api.init_state(cfg, n_slots, cache_len,
                                     jnp.bfloat16 if policy.dtype == "bf16"
                                     else jnp.float32)

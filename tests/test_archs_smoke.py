@@ -7,6 +7,7 @@ import pytest
 from repro.configs.base import RunPolicy, ShapeSpec, get_config, list_archs
 from repro.configs.all_archs import smoke_config
 from repro.models import api
+from repro.serve.engine import _update_slot
 from repro.train.optimizer import OptConfig
 from repro.train.train_step import (make_decode_step, make_init_opt,
                                     make_prefill_step, make_train_step)
@@ -79,6 +80,46 @@ def test_prefill_decode_consistency(arch, key):
     scale = float(jnp.max(jnp.abs(full_logits))) + 1e-6
     assert err1 / scale < 1e-4, f"prefill mismatch {err1}"
     assert err2 / scale < 1e-4, f"decode mismatch {err2}"
+
+
+@pytest.mark.parametrize("arch", ARCHS)
+def test_multi_step_decode_matches_forward(arch, key):
+    """Lanes prefilled at different lengths, written into one batched state
+    by the engine's slot update, then decoded together for several steps
+    (donating the state, as the engine does): each lane's logits at each
+    position equal one full forward pass over that lane.  The window archs'
+    attention rings (16 positions) wrap during decode."""
+    cfg = smoke_config(arch)
+    pol = RunPolicy(remat="none", dtype="f32",
+                    capacity_factor=float(max(cfg.n_experts, 1)))
+    params = api.init(cfg, key)
+    lens, steps = (9, 13, 18), 6        # positions, image prefix included
+    B, T = len(lens), max(lens) + steps
+    # one forward over every lane's whole sequence; causal, so a lane's
+    # logits at a position see only what decode has seen there
+    tb = api.synthetic_batch(cfg, ShapeSpec("t", "train", T, B), key)
+    tb.pop("labels")
+    full = jax.jit(lambda p, b: api.forward(p, b, cfg, pol)[0])(params, tb)
+    # a window arch's attention state is a ring of min(T, window) rows
+    prefill = jax.jit(make_prefill_step(
+        cfg, pol, min(T, cfg.window) if cfg.window else T))
+    decode = jax.jit(make_decode_step(cfg, pol), donate_argnums=1)
+    off = cfg.n_prefix if cfg.frontend == "vit" else 0
+    state = api.init_state(cfg, B, T, jnp.float32)
+    for b, S in enumerate(lens):
+        lane = {k: v[b:b + 1] for k, v in tb.items()}
+        lane["tokens"] = lane["tokens"][:, :S - off]
+        _, state1 = prefill(params, lane)
+        state = _update_slot(state, state1, b)
+    scale = float(jnp.max(jnp.abs(full))) + 1e-6
+    for j in range(steps):
+        pos = jnp.asarray([S + j for S in lens], jnp.int32)
+        toks = jnp.stack([tb["tokens"][b, S - off + j]
+                          for b, S in enumerate(lens)])[:, None]
+        logits, state = decode(params, state,
+                               {"tokens": toks, "position": pos})
+        err = float(jnp.max(jnp.abs(logits - full[jnp.arange(B), pos])))
+        assert err / scale < 1e-4, (j, err)
 
 
 def test_full_configs_exact_dims():

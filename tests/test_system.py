@@ -142,6 +142,43 @@ def test_serving_logits_match_full_forward():
     assert reqs[0].logits is None
 
 
+def test_serving_decode_donates_its_state():
+    """Decode takes the state donated: after a step the previous state's
+    buffers are gone (each layer's cache written in place), or, on a
+    backend that does not donate, the program marks every leaf donated."""
+    cfg = smoke_config("qwen2-1.5b")
+    pol = RunPolicy(remat="none", dtype="f32")
+    params = api.init(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(cfg, pol, params, n_slots=2, cache_len=32)
+    eng.add_request(Request(rid=0, prompt=np.arange(5, dtype=np.int32),
+                            max_new_tokens=4))
+    eng.step()
+    old = jax.tree.leaves(eng.state)
+    eng.step()
+    assert len(eng.completed) == 0 and eng.stats["decode_steps"] == 2
+    if not all(a.is_deleted() for a in old):
+        batch = {"tokens": jnp.zeros((2, 1), jnp.int32),
+                 "position": jnp.zeros((2,), jnp.int32)}
+        text = eng.decode.lower(eng.params, eng.state, batch).as_text()
+        assert text.count("tf.aliasing_output") == len(old)
+
+
+def test_serving_slot_update_is_one_program():
+    """Admission writes any lane with one program: the slot is traced, so
+    set-up does not load a program a lane."""
+    cfg = smoke_config("qwen2-1.5b")
+    pol = RunPolicy(remat="none", dtype="f32")
+    params = api.init(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(cfg, pol, params, n_slots=3, cache_len=32)
+    before = eng._update._cache_size()      # the jit cache is per process
+    for i in range(3):
+        eng.add_request(Request(rid=i, prompt=np.arange(4, dtype=np.int32),
+                                max_new_tokens=4))
+    eng.step()                      # admits all three, one to each lane
+    assert all(r is not None for r in eng.slot_req)
+    assert eng._update._cache_size() - before <= 1
+
+
 def test_launcher_trains_checkpoints_and_resumes_on_request(tmp_path,
                                                             monkeypatch):
     """launch/train.main: finite losses, a checkpoint in --ckpt-dir, a

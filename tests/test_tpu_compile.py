@@ -1,13 +1,16 @@
 """Compiles for a described TPU v5e, without a chip: the Pallas kernels at the
 widths the chip smoke runs them (the flash kernels at their chosen blocks,
-at both benchmark cells' shapes), and one qwen2-1.5b prefill cell on the
-kernel path.  Mosaic refuses here what interpret mode accepts: block shapes
-off the (8, 128) tiling, more VMEM than a kernel may use, primitives it
-cannot lower.  Nothing runs, so these say nothing about results or times.
+at both benchmark cells' shapes), one qwen2-1.5b prefill cell on the
+kernel path, the serving cell's qwen2-1.5b decode, and a one-lane decode
+with the cache sharded over four chips.  Mosaic refuses here what
+interpret mode accepts: block shapes off the (8, 128) tiling, more VMEM
+than a kernel may use, primitives it cannot lower.  Nothing runs, so these
+say nothing about results or times.
 
 The topology is described inside a fixture: only one process may load the
 TPU library, so describing it at import would break the other test workers.
 """
+import math
 import os
 import re
 
@@ -19,12 +22,15 @@ from jax.sharding import Mesh, SingleDeviceSharding
 
 from repro import hw
 from repro.configs.base import RunPolicy, ShapeSpec, get_config
+from repro.core.benchscale import BENCH_SHAPES, bench_config
 from repro.core.counters import measure_cell
 from repro.kernels.decode_attention import flash_decode
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.rglru_scan import rglru_scan
 from repro.kernels.rwkv6_kernel import rwkv6_wkv
 from repro.launch.steps import build_cell
+from repro.models import api
+from repro.train.train_step import make_decode_step
 
 
 @pytest.fixture(scope="module")
@@ -125,3 +131,48 @@ def test_qwen2_prefill_cell_compiles_with_kernels(topo):
     m = measure_cell(cell, chip)
     assert m.tpu_custom_calls > 0
     assert 0 < m.memory["peak_bytes"] < chip.hbm_bytes
+
+
+def test_qwen2_decode_writes_each_layer_cache_in_place(topo):
+    """The serving cell's decode (qwen2-1.5b, 64 lanes x 2688 positions) on
+    one described chip, jitted with the state donated as ``ServingEngine``
+    jits it: the whole state is aliased to the output, the layers are
+    unrolled (no loop slicing each layer's cache out of a stack and writing
+    it back), and the program accesses at most 1.5x the bytes of the
+    weights plus one read of the cache."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    cfg = get_config("qwen2-1.5b")
+    shape = ShapeSpec("decode", "decode", 2688, 64)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda s: jax.ShapeDtypeStruct(
+            s.shape, s.dtype, sharding=one_chip), tree)
+
+    def nbytes(tree):
+        return sum(math.prod(s.shape) * s.dtype.itemsize
+                   for s in jax.tree.leaves(tree))
+
+    params = on_chip(api.abstract_params(cfg, BF))
+    state = on_chip(api.state_specs(cfg, shape, BF)[0])
+    batch = on_chip(api.input_specs(cfg, shape, BF)[0])
+    step = make_decode_step(cfg, RunPolicy(use_pallas=True, remat="none"))
+    compiled = jax.jit(step, donate_argnums=1).lower(
+        params, state, batch).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes >= nbytes(state)
+    assert not re.search(r"\bwhile\(", compiled.as_text())
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["bytes accessed"] <= 1.5 * (nbytes(params) + nbytes(state))
+
+
+def test_one_lane_decode_writes_the_cache_on_its_shards(topo):
+    """A one-lane decode (qwen2-1.5b's search cell at 8192 positions) with
+    each layer's cache sharded by sequence over four described chips: the
+    row write stays on the shard that holds the row, so the program
+    gathers nothing."""
+    mesh = Mesh(np.asarray(topo.devices).reshape(1, 4), ("data", "model"))
+    cell = build_cell(bench_config("qwen2-1.5b"), BENCH_SHAPES["long_s"],
+                      RunPolicy(remat="none", params_f32=False), mesh)
+    assert jax.tree.leaves(cell.in_shardings[1])[0].spec[1] == "model"
+    text = cell.lower().compile().as_text()
+    assert not re.search(r" all-gather(?:-start)?\(", text)

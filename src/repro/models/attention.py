@@ -1,7 +1,10 @@
 """GQA/MQA/MHA attention with RoPE, optional QKV bias, sliding window, KV cache.
 
-Three execution paths (all numerically equivalent where applicable):
+``self_attention`` is the one full-sequence path, for training and prefill;
+``attend`` chooses how it attends (all numerically equivalent where
+applicable):
 
+* ``pallas``   — the Pallas flash kernel, under ``use_pallas``.
 * ``plain``    — materializes (Sq, Skv) scores; used for training at moderate
                  seq (grads are simple; remat recomputes in bwd).
 * ``blocked``  — online-softmax scan over KV blocks, O(S) live memory; used for
@@ -11,7 +14,9 @@ Three execution paths (all numerically equivalent where applicable):
                  O(S·W) FLOPs; used by window archs (recurrentgemma, mixtral)
                  at long sequence.
 
-Decode attends one query token against a (possibly ring-buffered) cache.
+Decode attends one query token against the cache; ``cache_layout`` decides
+the cache's rows and whether they are a ring, for the decode state, the
+cache prefill builds and the decode step's writes.
 """
 from __future__ import annotations
 
@@ -40,7 +45,7 @@ def attn_specs(d_model: int, n_heads: int, n_kv: int, d_head: int, bias: bool):
     return s
 
 
-def qkv_proj(p, x, n_heads, n_kv, d_head, positions, rope_theta, use_rope=True):
+def qkv_proj(p, x, positions, cfg):
     """x: (B,S,D) -> q (B,S,KV,G,dh), k,v (B,S,KV,dh); RoPE applied."""
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"])
@@ -49,12 +54,12 @@ def qkv_proj(p, x, n_heads, n_kv, d_head, positions, rope_theta, use_rope=True):
         q = q + p["bq"].astype(q.dtype)
         k = k + p["bk"].astype(k.dtype)
         v = v + p["bv"].astype(v.dtype)
-    if use_rope:
-        q = apply_rope(q.swapaxes(1, 2), positions[:, None, :], rope_theta).swapaxes(1, 2)
-        k = apply_rope(k.swapaxes(1, 2), positions[:, None, :], rope_theta).swapaxes(1, 2)
-    g = n_heads // n_kv
+    if cfg.use_rope:
+        q = apply_rope(q.swapaxes(1, 2), positions[:, None, :], cfg.rope_theta).swapaxes(1, 2)
+        k = apply_rope(k.swapaxes(1, 2), positions[:, None, :], cfg.rope_theta).swapaxes(1, 2)
+    g = cfg.n_heads // cfg.n_kv_heads
     B, S = x.shape[:2]
-    q = q.reshape(B, S, n_kv, g, d_head)
+    q = q.reshape(B, S, cfg.n_kv_heads, g, cfg.d_head)
     return q, k, v
 
 
@@ -166,17 +171,47 @@ def local_chunk_attention(q, k, v, positions_q, positions_kv, window):
     return out[:, :S]
 
 
-def cache_shapes(batch, cache_len, n_kv, d_head, dtype):
-    return {
-        "k": jax.ShapeDtypeStruct((batch, cache_len, n_kv, d_head), dtype),
-        "v": jax.ShapeDtypeStruct((batch, cache_len, n_kv, d_head), dtype),
-        "pos": jax.ShapeDtypeStruct((batch, cache_len), jnp.int32),
-    }
+def cache_layout(cache_len, window):
+    """An attention layer's decode cache: ``(rows, ring)``.  A window
+    architecture keeps a ring of ``min(cache_len, window)`` rows, position
+    ``t`` in row ``t % rows``; otherwise ``cache_len`` rows in position
+    order."""
+    if window is None:
+        return cache_len, False
+    return min(cache_len, window), True
+
+
+def cache_shapes(batch, cache_len, cfg, dtype):
+    rows, _ = cache_layout(cache_len, cfg.window)
+    kv = jax.ShapeDtypeStruct((batch, rows, cfg.n_kv_heads, cfg.d_head), dtype)
+    return {"k": kv, "v": kv,
+            "pos": jax.ShapeDtypeStruct((batch, rows), jnp.int32)}
 
 
 CACHE_AXES = {"k": ("batch", "cache_seq", "kv_heads", "head_dim"),
               "v": ("batch", "cache_seq", "kv_heads", "head_dim"),
               "pos": ("batch", "cache_seq")}
+
+
+@jax.named_scope("kv_cache")
+def cache_from_kv(k, v, positions, cache_len, window):
+    """The decode cache of prefilled sequences, laid out as ``cache_layout``
+    says: a ring holds the last ``rows`` positions, each in its row; else
+    the sequence is padded to the cache's rows.  k, v: (B,S,KV,dh)."""
+    rows, ring = cache_layout(cache_len, window)
+    B, S = k.shape[:2]
+    if ring and S > rows:
+        kk, vv, pos = k[:, -rows:], v[:, -rows:], positions[:, -rows:]
+        slot = pos % rows
+        bidx = jnp.arange(B)[:, None]
+        ck = jnp.zeros((B, rows) + k.shape[2:], k.dtype).at[bidx, slot].set(kk)
+        cv = jnp.zeros_like(ck).at[bidx, slot].set(vv)
+        cp = jnp.full((B, rows), -1, jnp.int32).at[bidx, slot].set(pos)
+        return {"k": ck, "v": cv, "pos": cp}
+    pad = rows - S
+    return {"k": jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))),
+            "v": jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))),
+            "pos": jnp.pad(positions, ((0, 0), (0, pad)), constant_values=-1)}
 
 
 def _write_rows(cache, slot, rows):
@@ -197,35 +232,32 @@ def _write_rows(cache, slot, rows):
             for n in cache}
 
 
-def decode_attention(p, cache, x, position, *, n_heads, n_kv, d_head,
-                     rope_theta, window=None, use_rope=True):
+def decode_attention(p, cache, x, position, cfg):
     """One-token decode. x: (B,1,D); position: (B,) int32 current index.
 
-    Cache is a ring buffer when ``window`` is set (slot = pos % len), else a
-    linear buffer (slot = pos).  K is stored post-RoPE.
-    Returns (attn_out (B,1,KV,G,dh), new_cache).
+    K is stored post-RoPE, in the row ``cache_layout`` gives the position.
+    Returns (out (B,1,D), new_cache).
     """
     T = cache["k"].shape[1]
-    q, k, v = qkv_proj(p, x, n_heads, n_kv, d_head,
-                       position[:, None], rope_theta, use_rope)
-    slot = position % T if window is not None else jnp.minimum(position, T - 1)
+    q, k, v = qkv_proj(p, x, position[:, None], cfg)
+    _, ring = cache_layout(T, cfg.window)
+    slot = position % T if ring else jnp.minimum(position, T - 1)
 
     with jax.named_scope("kv_cache"):
         new_cache = _write_rows(cache, slot, {"k": k[:, 0], "v": v[:, 0],
                                               "pos": position})
     kk, vv, pos_kv = new_cache["k"], new_cache["v"], new_cache["pos"]
-    g = n_heads // n_kv
     q = maybe_constrain(q, ("batch", None, "kv_heads", "heads", "head_dim"))
-    s = jnp.einsum("bqkgd,btkd->bkgqt", q, kk).astype(jnp.float32) / np.sqrt(d_head)
+    s = jnp.einsum("bqkgd,btkd->bkgqt", q, kk).astype(jnp.float32) / np.sqrt(cfg.d_head)
     pq = position[:, None, None, None, None]
     pt = pos_kv[:, None, None, None, :]
     mask = (pt >= 0) & (pt <= pq)
-    if window is not None:
-        mask &= pt > pq - window
+    if cfg.window is not None:
+        mask &= pt > pq - cfg.window
     s = jnp.where(mask, s, NEG_INF)
     w = _softmax_f32(s)
     out = jnp.einsum("bkgqt,btkd->bqkgd", w.astype(vv.dtype), vv)
-    return out, new_cache
+    return out_proj(p, out), new_cache
 
 
 def out_proj(p, attn_out):
@@ -246,19 +278,37 @@ def pallas_attention(q, k, v, window=None):
     return o.reshape(B, KV, G, S, dh).transpose(0, 3, 1, 2, 4)
 
 
-def full_attention(p, x, positions, *, n_heads, n_kv, d_head, rope_theta,
-                   window=None, impl="plain", use_rope=True, block=512):
-    """Full-sequence self-attention (train / prefill). Returns (B,S,D)."""
-    q, k, v = qkv_proj(p, x, n_heads, n_kv, d_head, positions, rope_theta, use_rope)
+def attend(q, k, v, positions, window, policy):
+    """Causal self-attention of a whole sequence, by the implementation the
+    policy and the length choose: the flash kernel under ``use_pallas``;
+    else ``policy.attn_impl``, where ``auto`` takes ``local`` for a window
+    shorter than half the sequence, ``blocked`` from 2048 positions and
+    ``plain`` below.  ``local`` applies only to such a window, and is
+    ``plain`` otherwise."""
+    if policy.use_pallas:
+        return pallas_attention(q, k, v, window)
+    S = q.shape[1]
+    local = window is not None and S > 2 * window
+    impl = policy.attn_impl
+    if impl == "auto":
+        impl = "local" if local else "blocked" if S >= 2048 else "plain"
+    if impl == "local" and local:
+        return local_chunk_attention(q, k, v, positions, positions, window)
+    if impl == "blocked":
+        return blocked_attention(q, k, v, positions, positions, window)
+    return plain_attention(q, k, v, positions, positions, window)
+
+
+def self_attention(p, x, positions, cfg, policy, cache_len=None):
+    """Full-sequence causal self-attention (training, and prefill when
+    ``cache_len`` is given).  x: (B,S,D) -> (out (B,S,D), the decode cache
+    built from this pass's keys and values, or None)."""
+    q, k, v = qkv_proj(p, x, positions, cfg)
     q = maybe_constrain(q, ("batch", "seq_q", "kv_heads", "heads", "head_dim"))
     k = maybe_constrain(k, ("batch", None, "kv_heads", "head_dim"))
-    if impl == "pallas":
-        o = pallas_attention(q, k, v, window)
-    elif impl == "local" and window is not None and x.shape[1] > 2 * window:
-        o = local_chunk_attention(q, k, v, positions, positions, window)
-    elif impl == "blocked":
-        o = blocked_attention(q, k, v, positions, positions, window, block=block)
-    else:
-        o = plain_attention(q, k, v, positions, positions, window)
+    o = attend(q, k, v, positions, cfg.window, policy)
     o = maybe_constrain(o, ("batch", "seq_q", "kv_heads", "heads", "head_dim"))
-    return out_proj(p, o)
+    out = out_proj(p, o)
+    if cache_len is None:
+        return out, None
+    return out, cache_from_kv(k, v, positions, cache_len, cfg.window)

@@ -60,28 +60,10 @@ def apply_rope(x, positions, theta: float):
     return out.astype(x.dtype)
 
 
-def sinusoidal_positions(seq_len: int, d: int):
-    pos = np.arange(seq_len)[:, None]
-    i = np.arange(d // 2)[None, :]
-    ang = pos / (10000 ** (2 * i / d))
-    out = np.concatenate([np.sin(ang), np.cos(ang)], axis=-1)
-    return jnp.asarray(out, jnp.float32)
-
-
 # ----------------------------------------------------------------- embeddings
-
-def embed_specs(vocab: int, d: int):
-    return {"table": ParamSpec((vocab, d), ("vocab", "embed"), "embed")}
-
 
 def embed_lookup(p, tokens):
     return jnp.take(p["table"], tokens, axis=0)
-
-
-def unembed(p, x):
-    # logits in f32 for a stable softmax/loss
-    return jnp.einsum("...d,vd->...v", x.astype(jnp.float32),
-                      p["table"].astype(jnp.float32))
 
 
 # ----------------------------------------------------------------------- MLPs

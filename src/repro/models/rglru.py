@@ -60,18 +60,22 @@ def _conv_full(p, xb):
     return out
 
 
-def apply_rglru(p, x, *, n_blocks: int, use_pallas: bool = False):
-    """Full-sequence recurrent block. x: (B,S,D) -> (B,S,D)."""
+def apply_rglru(p, x, *, n_blocks: int, use_pallas: bool = False,
+                return_state: bool = False):
+    """Full-sequence recurrent block. x: (B,S,D) -> (out (B,S,D), state):
+    with ``return_state`` the decode state after the last position (its
+    ``h`` and the conv's last ``CONV_K - 1`` inputs), else None.  The Pallas
+    scan runs under ``use_pallas`` when no state is asked for."""
     xb = jnp.einsum("bsd,dw->bsw", x, p["wx"])
     xb = maybe_constrain(xb, ("batch", None, "rec_width"))
-    xb = _conv_full(p, xb)
-    r, i = _gates(p, xb, n_blocks)
+    xc = _conv_full(p, xb)
+    r, i = _gates(p, xc, n_blocks)
     log_a = _log_a(p, r)                                   # (B,S,W) f32
     a = jnp.exp(log_a)
     gated = jnp.sqrt(jnp.maximum(1.0 - jnp.exp(2.0 * log_a), 1e-12)) \
-        * (i * xb.astype(jnp.float32))
+        * (i * xc.astype(jnp.float32))
 
-    if use_pallas:
+    if use_pallas and not return_state:
         from ..kernels import ops
         h = ops.rglru(a, gated, use_pallas=True)
     else:
@@ -82,13 +86,11 @@ def apply_rglru(p, x, *, n_blocks: int, use_pallas: bool = False):
 
         _, h = jax.lax.associative_scan(combine, (a, gated), axis=1)
     y = jax.nn.gelu(jnp.einsum("bsd,dw->bsw", x, p["wy"]))
-    out = (h.astype(x.dtype) * y)
-    return jnp.einsum("bsw,wd->bsd", out, p["wo"])
-
-
-def init_rglru_state(batch: int, width: int, dtype):
-    return {"h": jnp.zeros((batch, width), jnp.float32),
-            "conv": jnp.zeros((batch, CONV_K - 1, width), dtype)}
+    out = jnp.einsum("bsw,wd->bsd", h.astype(x.dtype) * y, p["wo"])
+    if not return_state:
+        return out, None
+    hist = jnp.pad(xb, ((0, 0), (CONV_K - 1, 0), (0, 0)))[:, -(CONV_K - 1):]
+    return out, {"h": h[:, -1], "conv": hist}
 
 
 def rglru_state_shapes(batch: int, width: int, dtype):

@@ -7,8 +7,10 @@ with per-channel data-dependent decay w_t = exp(-exp(w0 + lora(x_w))) in (0,1)
 and data-dependent token-shift interpolation (ddlerp) for the five streams
 (w,k,v,r,g), as in arXiv:2404.05892.
 
-The full-sequence path scans over time (exact oracle; the TPU Pallas kernel
-``kernels/rwkv6_kernel.py`` computes the same recurrence chunk-parallel).
+The full-sequence time-mix computes the recurrence by the TPU Pallas kernel
+(``kernels/rwkv6_kernel.py``, chunk-parallel), by a chunked or a
+sequence-parallel chunked formulation, or by a scan over time (the exact
+oracle); all but the kernel also give the final state.
 """
 from __future__ import annotations
 
@@ -69,7 +71,7 @@ def _ddlerp(p, x, xx):
 def _wkv_scan(r, k, v, w_log, u):
     """Exact sequential WKV. r,k,v,w_log: (B,S,H,hs); u: (H,hs).
 
-    Returns o: (B,S,H,hs). State: (B,H,hs,hs) f32.
+    Returns o (B,S,H,hs) f32 and the final state (B,H,hs,hs) f32.
     """
     B, S, H, hs = r.shape
     rf = r.astype(jnp.float32).swapaxes(0, 1)
@@ -86,8 +88,8 @@ def _wkv_scan(r, k, v, w_log, u):
         return state, o
 
     s0 = jnp.zeros((B, H, hs, hs), jnp.float32)
-    _, o = jax.lax.scan(step, s0, (rf, kf, vf, wf))
-    return o.swapaxes(0, 1)                                   # (B,S,H,hs)
+    final, o = jax.lax.scan(step, s0, (rf, kf, vf, wf))
+    return o.swapaxes(0, 1), final
 
 
 def wkv_chunked(r, k, v, w_log, u, chunk: int = 16):
@@ -213,8 +215,22 @@ def _group_norm(p, o):
     return y * p["ln_scale"].astype(y.dtype) + p["ln_bias"].astype(y.dtype)
 
 
-def apply_timemix(p, x, *, n_heads, head_size, wkv_fn=None):
-    """Full-sequence time-mix. x: (B,S,D)."""
+def _wkv_kernel(r, k, v, w_log, u):
+    """The Pallas WKV kernel (``kernels/rwkv6_kernel.py``) in this module's
+    (B,S,H,hs) layout; the kernel's is (B,H,S,hs).  Returns o only."""
+    from ..kernels import ops
+
+    def tr(t):
+        return t.transpose(0, 2, 1, 3)
+    return tr(ops.rwkv6(tr(r), tr(k), tr(v), tr(w_log), u, use_pallas=True))
+
+
+def apply_timemix(p, x, *, n_heads, head_size, use_pallas=False,
+                  return_state=False):
+    """Full-sequence time-mix. x: (B,S,D) -> (out (B,S,D), state): with
+    ``return_state`` the decode state after the last position (``tm_x``,
+    ``wkv``), else None.  The Pallas kernel runs under ``use_pallas`` when
+    no state is asked for."""
     B, S, D = x.shape
     xx = jnp.pad(x, ((0, 0), (1, 0), (0, 0)))[:, :S]          # prev token
     mixed = _ddlerp(p, x, xx)                                 # (B,S,5,D)
@@ -228,19 +244,20 @@ def apply_timemix(p, x, *, n_heads, head_size, wkv_fn=None):
                      @ p["wB"].astype(jnp.float32))
     w_log = w_log.reshape(B, S, n_heads, head_size)
     r = maybe_constrain(r, ("batch", None, "rwkv_heads", "head_dim"))
-    if wkv_fn is None:
-        # chunked by default at seq >= 64 (~16x less state HBM traffic);
-        # sequence-parallel chunked at long seq (in-place ys writes under
-        # sequence sharding); exact sequential scan for short sequences
-        if S >= 4096 and S % 256 == 0:
-            wkv_fn = lambda *a: wkv_seq_parallel(*a)[0]
-        elif S >= 64:
-            wkv_fn = lambda *a: wkv_chunked(*a)[0]
-        else:
-            wkv_fn = _wkv_scan
-    o = wkv_fn(r, k, v, w_log, p["u"])
+    # chunked by default at seq >= 64 (~16x less state HBM traffic);
+    # sequence-parallel chunked at long seq (in-place ys writes under
+    # sequence sharding); exact sequential scan for short sequences
+    if use_pallas and not return_state:
+        o, final = _wkv_kernel(r, k, v, w_log, p["u"]), None
+    elif S >= 4096 and S % 256 == 0:
+        o, final = wkv_seq_parallel(r, k, v, w_log, p["u"])
+    elif S >= 64:
+        o, final = wkv_chunked(r, k, v, w_log, p["u"])
+    else:
+        o, final = _wkv_scan(r, k, v, w_log, p["u"])
     o = _group_norm(p, o.astype(jnp.float32)).astype(x.dtype) * g
-    return jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+    out = jnp.einsum("bshk,hkd->bsd", o, p["wo"])
+    return out, ({"tm_x": x[:, -1], "wkv": final} if return_state else None)
 
 
 def apply_channelmix(p, x):
@@ -254,14 +271,6 @@ def apply_channelmix(p, x):
 
 
 # ----------------------------------------------------------------- decode
-
-def init_rwkv_state(batch, d, n_heads, head_size, dtype):
-    return {
-        "tm_x": jnp.zeros((batch, d), dtype),       # prev token (time-mix)
-        "cm_x": jnp.zeros((batch, d), dtype),       # prev token (channel-mix)
-        "wkv": jnp.zeros((batch, n_heads, head_size, head_size), jnp.float32),
-    }
-
 
 def rwkv_state_shapes(batch, d, n_heads, head_size, dtype):
     return {

@@ -5,9 +5,11 @@ dense/MoE GQA attention blocks, RG-LRU recurrent blocks, RWKV6 blocks,
 VLM patch-prefix and multi-codebook audio frontends.  Layers are grouped
 into repeating *pattern units* (e.g. ("rec","rec","attn") for
 recurrentgemma); in training and prefill, units are either scanned
-(stacked params, production default) or unrolled (D3 search factor).  The
-decode state is one tree per layer, and decode always unrolls the layers,
-so that each layer's cache is a buffer of its own, written in place.
+(stacked params, production default) or unrolled (D3 search factor).
+Training and prefill run the same full-sequence block; prefill only asks
+it for the decode state as well.  The decode state is one tree per layer,
+and decode always unrolls the layers, so that each layer's cache is a
+buffer of its own, written in place.
 
 Named scopes mark the layers in the compiled programs' metadata, and so in
 the device trace: ``embed``, ``layers`` (the unit loop or scan, and the
@@ -17,7 +19,6 @@ norm and residual), ``attn/kv_cache`` (the cache written), ``final_norm``,
 """
 from __future__ import annotations
 
-import functools
 from typing import Any
 
 import jax
@@ -31,7 +32,7 @@ from . import rwkv6 as rwkv
 from .layers import (apply_glu_mlp, apply_norm, apply_plain_mlp, embed_lookup,
                      glu_mlp_specs, norm_specs, plain_mlp_specs)
 from .module import ParamSpec, map_specs, stack_layer_specs
-from ..configs.base import ModelConfig, RunPolicy, ShapeSpec
+from ..configs.base import ModelConfig, RunPolicy
 from ..launch.sharding import maybe_constrain
 
 # ----------------------------------------------------------------- spec build
@@ -71,7 +72,7 @@ def build_specs(cfg: ModelConfig):
     n_units, tail = n_units_tail(cfg)
     unit = {f"b{i}": block_specs(cfg, bt) for i, bt in enumerate(cfg.block_pattern)}
     specs: dict[str, Any] = {
-        "embed": _embed_specs(cfg),
+        "embed": _table_specs(cfg),
         "units": map_specs(lambda s: stack_layer_specs(s, n_units), unit),
         "final_norm": norm_specs(cfg.d_model, cfg.norm),
     }
@@ -79,7 +80,7 @@ def build_specs(cfg: ModelConfig):
         specs["tail"] = {f"t{i}": block_specs(cfg, cfg.block_pattern[i])
                          for i in range(tail)}
     if not cfg.tie_embeddings:
-        specs["unembed"] = _unembed_specs(cfg)
+        specs["unembed"] = _table_specs(cfg)
     if cfg.frontend == "vit":
         specs["projector"] = {
             "ln": norm_specs(cfg.d_frontend, cfg.norm),
@@ -89,15 +90,8 @@ def build_specs(cfg: ModelConfig):
     return specs
 
 
-def _embed_specs(cfg):
-    if cfg.frontend == "encodec":
-        return {"table": ParamSpec((cfg.n_codebooks, cfg.vocab_size, cfg.d_model),
-                                   (None, "vocab", "embed"), "embed")}
-    return {"table": ParamSpec((cfg.vocab_size, cfg.d_model),
-                               ("vocab", "embed"), "embed")}
-
-
-def _unembed_specs(cfg):
+def _table_specs(cfg):
+    """The embedding table, and an untied unembedding's."""
     if cfg.frontend == "encodec":
         return {"table": ParamSpec((cfg.n_codebooks, cfg.vocab_size, cfg.d_model),
                                    (None, "vocab", "embed"), "embed")}
@@ -147,228 +141,93 @@ def unembed_logits(params, cfg: ModelConfig, x):
     return logits
 
 
-# ------------------------------------------------------------ full-seq blocks
+# --------------------------------------------------------------------- blocks
 
-def _resolve_attn_impl(cfg, policy, S):
-    if policy.use_pallas:
-        return "pallas"
-    if policy.attn_impl != "auto":
-        return policy.attn_impl
-    if cfg.window is not None and S > 2 * cfg.window:
-        return "local"
-    if S >= 2048:
-        return "blocked"     # flash-attention algebra: matches the TPU kernel
-    return "plain"
+def _ffn(p, h, cfg: ModelConfig, policy: RunPolicy):
+    """An attention block's feed-forward: routed experts, a plain MLP or a
+    GLU.  Returns (out, the router's (2,) f32 statistics or None)."""
+    if cfg.n_experts:
+        m, a = moe_mod.apply_moe(p, h, top_k=cfg.top_k, act=cfg.act,
+                                 capacity_factor=policy.capacity_factor)
+        return m, jnp.stack([a["lb_loss"], a["dropped_frac"]])
+    if "wi" in p:
+        return apply_plain_mlp(p, h, cfg.act), None
+    return apply_glu_mlp(p, h, cfg.act), None
 
 
 def apply_block_full(bt, p, x, positions, cfg: ModelConfig, policy: RunPolicy,
                      cache_len: int | None = None):
-    """Returns (x, aux (2,) f32, state-or-None)."""
+    """One block over the whole sequence: training, or prefill when
+    ``cache_len`` is given.  Returns (x, aux (2,) f32, the block's decode
+    state after the last position, or None without ``cache_len``)."""
     aux = jnp.zeros((2,), jnp.float32)
-    state = None
-    S = x.shape[1]
+    want_state = cache_len is not None
     if bt == "attn":
         with jax.named_scope("attn"):
-            h = apply_norm(p["ln1"], x, cfg.norm)
-            impl = _resolve_attn_impl(cfg, policy, S)
-            kw = dict(n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
-                      d_head=cfg.d_head, rope_theta=cfg.rope_theta,
-                      window=cfg.window, use_rope=cfg.use_rope)
-            if cache_len is None:
-                a = attn.full_attention(p["attn"], h, positions, impl=impl,
-                                        **kw)
-            else:
-                q, k, v = attn.qkv_proj(p["attn"], h, cfg.n_heads,
-                                        cfg.n_kv_heads, cfg.d_head, positions,
-                                        cfg.rope_theta, cfg.use_rope)
-                if impl == "pallas":
-                    o = attn.pallas_attention(q, k, v, cfg.window)
-                elif impl == "local":
-                    o = attn.local_chunk_attention(q, k, v, positions,
-                                                   positions, cfg.window)
-                elif impl == "blocked":
-                    o = attn.blocked_attention(q, k, v, positions, positions,
-                                               cfg.window)
-                else:
-                    o = attn.plain_attention(q, k, v, positions, positions,
-                                             cfg.window)
-                a = attn.out_proj(p["attn"], o)
-                state = _cache_from_kv(k, v, positions, cache_len, cfg)
+            a, state = attn.self_attention(
+                p["attn"], apply_norm(p["ln1"], x, cfg.norm), positions, cfg,
+                policy, cache_len)
             x = x + a
         with jax.named_scope("mlp"):
-            h2 = apply_norm(p["ln2"], x, cfg.norm)
-            if cfg.n_experts:
-                m, moe_aux = moe_mod.apply_moe(
-                    p["mlp"], h2, top_k=cfg.top_k, act=cfg.act,
-                    capacity_factor=policy.capacity_factor)
-                aux = jnp.stack([moe_aux["lb_loss"], moe_aux["dropped_frac"]])
-            elif "wi" in p["mlp"]:
-                m = apply_plain_mlp(p["mlp"], h2, cfg.act)
-            else:
-                m = apply_glu_mlp(p["mlp"], h2, cfg.act)
+            m, router = _ffn(p["mlp"], apply_norm(p["ln2"], x, cfg.norm), cfg,
+                             policy)
             x = x + m
+        if router is not None:
+            aux = router
     elif bt == "rec":
-        h = apply_norm(p["ln1"], x, cfg.norm)
-        if cache_len is None:
-            r = rg.apply_rglru(p["rec"], h, n_blocks=cfg.n_heads,
-                               use_pallas=policy.use_pallas)
-        else:
-            r, state = _rglru_with_state(p["rec"], h, cfg)
+        r, state = rg.apply_rglru(p["rec"], apply_norm(p["ln1"], x, cfg.norm),
+                                  n_blocks=cfg.n_heads,
+                                  use_pallas=policy.use_pallas,
+                                  return_state=want_state)
         x = x + r
-        h2 = apply_norm(p["ln2"], x, cfg.norm)
-        x = x + apply_glu_mlp(p["mlp"], h2, cfg.act)
+        x = x + apply_glu_mlp(p["mlp"], apply_norm(p["ln2"], x, cfg.norm),
+                              cfg.act)
     elif bt == "rwkv":
-        h = apply_norm(p["ln1"], x, cfg.norm)
-        if cache_len is None:
-            wkv_fn = None
-            if policy.use_pallas:
-                from ..kernels import ops
-
-                def wkv_fn(r, k, v, w_log, u):
-                    tr = lambda t: t.transpose(0, 2, 1, 3)
-                    o = ops.rwkv6(tr(r), tr(k), tr(v), tr(w_log), u,
-                                  use_pallas=True)
-                    return tr(o)
-            t = rwkv.apply_timemix(p["tm"], h, n_heads=cfg.n_heads,
-                                   head_size=cfg.head_size, wkv_fn=wkv_fn)
-        else:
-            t, state = _rwkv_with_state(p, h, x, cfg)
+        t, state = rwkv.apply_timemix(p["tm"], apply_norm(p["ln1"], x, cfg.norm),
+                                      n_heads=cfg.n_heads,
+                                      head_size=cfg.head_size,
+                                      use_pallas=policy.use_pallas,
+                                      return_state=want_state)
         x = x + t
         h2 = apply_norm(p["ln2"], x, cfg.norm)
         x = x + rwkv.apply_channelmix(p["cm"], h2)
-        if cache_len is not None:
-            state["cm_x"] = h2[:, -1]
+        if want_state:
+            state = {**state, "cm_x": h2[:, -1]}   # channel-mix's last input
     else:
         raise ValueError(bt)
     x = maybe_constrain(x, ("batch", "seq_q", "act_embed"))
     return x, aux, state
 
 
-@jax.named_scope("kv_cache")
-def _cache_from_kv(k, v, positions, cache_len, cfg):
-    B, S = k.shape[:2]
-    if cfg.window is not None and cache_len < S:
-        keep = cache_len
-        kk, vv, pos = k[:, -keep:], v[:, -keep:], positions[:, -keep:]
-        slot = pos % cache_len
-        bidx = jnp.arange(B)[:, None]
-        ck = jnp.zeros((B, cache_len) + k.shape[2:], k.dtype).at[bidx, slot].set(kk)
-        cv = jnp.zeros_like(ck).at[bidx, slot].set(vv)
-        cp = jnp.full((B, cache_len), -1, jnp.int32).at[bidx, slot].set(pos)
-        return {"k": ck, "v": cv, "pos": cp}
-    pad = cache_len - S
-    return {"k": jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))),
-            "v": jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))),
-            "pos": jnp.pad(positions, ((0, 0), (0, pad)), constant_values=-1)}
-
-
-def _rglru_with_state(p, h, cfg):
-    """RG-LRU full pass that also returns the decode state."""
-    xb = jnp.einsum("bsd,dw->bsw", h, p["wx"])
-    xb_conv = rg._conv_full(p, xb)
-    r, i = rg._gates(p, xb_conv, cfg.n_heads)
-    log_a = rg._log_a(p, r)
-    a = jnp.exp(log_a)
-    gated = jnp.sqrt(jnp.maximum(1.0 - jnp.exp(2.0 * log_a), 1e-12)) \
-        * (i * xb_conv.astype(jnp.float32))
-
-    def combine(c1, c2):
-        a1, b1 = c1
-        a2, b2 = c2
-        return a1 * a2, a2 * b1 + b2
-
-    _, hs = jax.lax.associative_scan(combine, (a, gated), axis=1)
-    y = jax.nn.gelu(jnp.einsum("bsd,dw->bsw", h, p["wy"]))
-    out = jnp.einsum("bsw,wd->bsd", hs.astype(h.dtype) * y, p["wo"])
-    K = rg.CONV_K  # conv state = last K-1 raw (pre-conv) inputs
-    hist = jnp.pad(xb, ((0, 0), (K - 1, 0), (0, 0)))[:, -(K - 1):, :]
-    state = {"h": hs[:, -1], "conv": hist}
-    return out, state
-
-
-def _rwkv_with_state(p, h, x_res, cfg):
-    B, S, D = h.shape
-    xx = jnp.pad(h, ((0, 0), (1, 0), (0, 0)))[:, :S]
-    mixed = rwkv._ddlerp(p["tm"], h, xx)
-    x_w, x_k, x_v, x_r, x_g = [mixed[:, :, i] for i in range(rwkv.FIVE)]
-    r = jnp.einsum("bsd,dhk->bshk", x_r, p["tm"]["wr"])
-    k = jnp.einsum("bsd,dhk->bshk", x_k, p["tm"]["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", x_v, p["tm"]["wv"])
-    g = jax.nn.silu(jnp.einsum("bsd,dhk->bshk", x_g, p["tm"]["wg"]))
-    w_log = -jnp.exp(p["tm"]["w0"].astype(jnp.float32)
-                     + jnp.einsum("bsd,dl->bsl", x_w, p["tm"]["wA"]).astype(jnp.float32)
-                     @ p["tm"]["wB"].astype(jnp.float32))
-    w_log = w_log.reshape(B, S, cfg.n_heads, cfg.head_size)
-    if S >= 4096 and S % 256 == 0:
-        o, final = rwkv.wkv_seq_parallel(r, k, v, w_log, p["tm"]["u"])
-    elif S >= 64:
-        o, final = rwkv.wkv_chunked(r, k, v, w_log, p["tm"]["u"])
-    else:
-        o, final = _wkv_scan_with_state(r, k, v, w_log, p["tm"]["u"])
-    o = rwkv._group_norm(p["tm"], o.astype(jnp.float32)).astype(h.dtype) * g
-    out = jnp.einsum("bshk,hkd->bsd", o, p["tm"]["wo"])
-    state = {"tm_x": h[:, -1], "cm_x": jnp.zeros_like(h[:, -1]), "wkv": final}
-    return out, state
-
-
-def _wkv_scan_with_state(r, k, v, w_log, u):
-    B, S, H, hs = r.shape
-    rf = r.astype(jnp.float32).swapaxes(0, 1)
-    kf = k.astype(jnp.float32).swapaxes(0, 1)
-    vf = v.astype(jnp.float32).swapaxes(0, 1)
-    wf = jnp.exp(w_log.astype(jnp.float32)).swapaxes(0, 1)
-    uf = u.astype(jnp.float32)
-
-    def step(state, inp):
-        rt, kt, vt, wt = inp
-        kv = kt[..., :, None] * vt[..., None, :]
-        o = jnp.einsum("bhk,bhkv->bhv", rt, state + uf[None, :, :, None] * kv)
-        return wt[..., :, None] * state + kv, o
-
-    s0 = jnp.zeros((B, H, hs, hs), jnp.float32)
-    final, o = jax.lax.scan(step, s0, (rf, kf, vf, wf))
-    return o.swapaxes(0, 1), final
-
-
-# -------------------------------------------------------------- decode blocks
-
 def apply_block_decode(bt, p, state, x, position, cfg: ModelConfig,
-                       policy: RunPolicy | None = None):
-    cf = policy.capacity_factor if policy is not None else 1.25
+                       policy: RunPolicy):
+    """One block for one token per lane.  Returns (x, the new state)."""
     if bt == "attn":
         with jax.named_scope("attn"):
-            h = apply_norm(p["ln1"], x, cfg.norm)
-            o, new_cache = attn.decode_attention(
-                p["attn"], state, h, position, n_heads=cfg.n_heads,
-                n_kv=cfg.n_kv_heads, d_head=cfg.d_head,
-                rope_theta=cfg.rope_theta, window=cfg.window,
-                use_rope=cfg.use_rope)
-            x = x + attn.out_proj(p["attn"], o)
+            a, state = attn.decode_attention(
+                p["attn"], state, apply_norm(p["ln1"], x, cfg.norm), position,
+                cfg)
+            x = x + a
         with jax.named_scope("mlp"):
-            h2 = apply_norm(p["ln2"], x, cfg.norm)
-            if cfg.n_experts:
-                m, _ = moe_mod.apply_moe(p["mlp"], h2, top_k=cfg.top_k,
-                                         act=cfg.act, capacity_factor=cf)
-            elif "wi" in p["mlp"]:
-                m = apply_plain_mlp(p["mlp"], h2, cfg.act)
-            else:
-                m = apply_glu_mlp(p["mlp"], h2, cfg.act)
-            return x + m, new_cache
+            m, _ = _ffn(p["mlp"], apply_norm(p["ln2"], x, cfg.norm), cfg,
+                        policy)
+            return x + m, state
     if bt == "rec":
-        h = apply_norm(p["ln1"], x, cfg.norm)
-        r, new_state = rg.decode_rglru(p["rec"], state, h, n_blocks=cfg.n_heads)
+        r, state = rg.decode_rglru(p["rec"], state,
+                                   apply_norm(p["ln1"], x, cfg.norm),
+                                   n_blocks=cfg.n_heads)
         x = x + r
-        h2 = apply_norm(p["ln2"], x, cfg.norm)
-        return x + apply_glu_mlp(p["mlp"], h2, cfg.act), new_state
+        return x + apply_glu_mlp(p["mlp"], apply_norm(p["ln2"], x, cfg.norm),
+                                 cfg.act), state
     if bt == "rwkv":
-        h = apply_norm(p["ln1"], x, cfg.norm)
-        t, tm_x, wkv_s = rwkv.decode_timemix(p["tm"], state, h,
-                                             n_heads=cfg.n_heads,
-                                             head_size=cfg.head_size)
+        t, tm_x, wkv = rwkv.decode_timemix(p["tm"], state,
+                                           apply_norm(p["ln1"], x, cfg.norm),
+                                           n_heads=cfg.n_heads,
+                                           head_size=cfg.head_size)
         x = x + t
-        h2 = apply_norm(p["ln2"], x, cfg.norm)
-        c, cm_x = rwkv.decode_channelmix(p["cm"], state, h2)
-        return x + c, {"tm_x": tm_x, "cm_x": cm_x, "wkv": wkv_s}
+        c, cm_x = rwkv.decode_channelmix(p["cm"], state,
+                                         apply_norm(p["ln2"], x, cfg.norm))
+        return x + c, {"tm_x": tm_x, "cm_x": cm_x, "wkv": wkv}
     raise ValueError(bt)
 
 
@@ -377,8 +236,7 @@ def apply_block_decode(bt, p, state, x, position, cfg: ModelConfig,
 def block_state_shapes(cfg: ModelConfig, bt: str, batch: int, cache_len: int,
                        dtype):
     if bt == "attn":
-        clen = min(cache_len, cfg.window) if cfg.window else cache_len
-        return attn.cache_shapes(batch, clen, cfg.n_kv_heads, cfg.d_head, dtype)
+        return attn.cache_shapes(batch, cache_len, cfg, dtype)
     if bt == "rec":
         return rg.rglru_state_shapes(batch, cfg.rec_width, dtype)
     if bt == "rwkv":
@@ -430,11 +288,12 @@ def _remat_wrap(fn, policy: RunPolicy):
 
 
 def forward(params, batch, cfg: ModelConfig, policy: RunPolicy,
-            return_cache: bool = False, cache_len: int | None = None):
+            cache_len: int | None = None):
     """Full-sequence forward.
 
-    Returns (logits, aux) for training (full-seq logits), or
-    (last_logits, aux, state) when return_cache (prefill).
+    Returns (logits, aux) for training (full-seq logits), or, with
+    ``cache_len`` (prefill), (last_logits, aux, state): the decode state,
+    one tree per layer as ``model_state_shapes`` gives it.
     """
     compute_dtype = jnp.bfloat16 if policy.dtype == "bf16" else jnp.float32
     cparams = jax.tree.map(lambda a: a.astype(compute_dtype)
@@ -443,17 +302,15 @@ def forward(params, batch, cfg: ModelConfig, policy: RunPolicy,
     x = maybe_constrain(x, ("batch", "seq_q", "act_embed"))
     pattern = cfg.block_pattern
     n_units, tail = n_units_tail(cfg)
-    cl = cache_len if return_cache else None
 
     def unit_fn(x, unit_params, positions):
         aux = jnp.zeros((2,), jnp.float32)
         states = []
         for i, bt in enumerate(pattern):
             x, a, st = apply_block_full(bt, unit_params[f"b{i}"], x, positions,
-                                        cfg, policy, cache_len=cl)
+                                        cfg, policy, cache_len)
             aux = aux + a
-            if cl is not None:
-                states.append(st)
+            states.append(st)
         return x, aux, states
 
     unit_fn_r = _remat_wrap(unit_fn, policy)
@@ -479,21 +336,16 @@ def forward(params, batch, cfg: ModelConfig, policy: RunPolicy,
                 layer_states += states
 
         for i in range(tail):
-            bt = pattern[i]
-            x, a, st = apply_block_full(bt, cparams["tail"][f"t{i}"], x,
-                                        positions, cfg, policy, cache_len=cl)
+            x, a, st = apply_block_full(pattern[i], cparams["tail"][f"t{i}"], x,
+                                        positions, cfg, policy, cache_len)
             aux = aux + a
-            if cl is not None:
-                layer_states.append(st)
+            layer_states.append(st)
 
     with jax.named_scope("final_norm"):
         x = apply_norm(cparams["final_norm"], x, cfg.norm)
-    if return_cache:
-        last = x[:, -1]
-        logits = unembed_logits(cparams, cfg, last)
-        return logits, aux, layer_states
-    logits = unembed_logits(cparams, cfg, x)
-    return logits, aux
+    if cache_len is None:
+        return unembed_logits(cparams, cfg, x), aux
+    return unembed_logits(cparams, cfg, x[:, -1]), aux, layer_states
 
 
 def decode_step(params, state, batch, cfg: ModelConfig, policy: RunPolicy):

@@ -143,7 +143,7 @@ def make_init_opt(cfg: ModelConfig, policy: RunPolicy, opt: OptConfig,
 def make_prefill_step(cfg: ModelConfig, policy: RunPolicy, cache_len: int):
     def prefill_step(params, batch):
         logits, aux, state = api.forward(params, batch, cfg, policy,
-                                         return_cache=True, cache_len=cache_len)
+                                         cache_len=cache_len)
         return logits, state
     return prefill_step
 

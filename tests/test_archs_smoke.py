@@ -100,9 +100,7 @@ def test_multi_step_decode_matches_forward(arch, key):
     tb = api.synthetic_batch(cfg, ShapeSpec("t", "train", T, B), key)
     tb.pop("labels")
     full = jax.jit(lambda p, b: api.forward(p, b, cfg, pol)[0])(params, tb)
-    # a window arch's attention state is a ring of min(T, window) rows
-    prefill = jax.jit(make_prefill_step(
-        cfg, pol, min(T, cfg.window) if cfg.window else T))
+    prefill = jax.jit(make_prefill_step(cfg, pol, T))
     decode = jax.jit(make_decode_step(cfg, pol), donate_argnums=1)
     off = cfg.n_prefix if cfg.frontend == "vit" else 0
     state = api.init_state(cfg, B, T, jnp.float32)

@@ -121,24 +121,32 @@ def test_serving_greedy_matches_decode_path():
     assert out == ref, (out, ref)
 
 
-def test_serving_logits_match_full_forward():
+@pytest.mark.parametrize("arch", ["qwen2-1.5b", "mixtral-8x7b",
+                                  "recurrentgemma-2b", "rwkv6-7b"])
+def test_serving_logits_match_full_forward(arch):
     """Logits a request collects (prefill, then cache decode on a shared
-    batch) equal one full forward pass over prompt plus output."""
-    cfg = smoke_config("qwen2-1.5b")
-    pol = RunPolicy(remat="none", dtype="f32")
+    batch) equal one full forward pass over prompt plus output.  Every block
+    kind: attention (MoE and a 16-position window in mixtral, whose ring the
+    longest prompt overruns and decode wraps), RG-LRU and RWKV."""
+    cfg = smoke_config(arch)
+    # dropless capacity: MoE token drops depend on grouping, which differs
+    # between the full forward and prefill + decode
+    pol = RunPolicy(remat="none", dtype="f32",
+                    capacity_factor=float(max(cfg.n_experts, 1)))
     params = api.init(cfg, jax.random.PRNGKey(0))
     eng = ServingEngine(cfg, pol, params, n_slots=2, cache_len=32)
-    reqs = [Request(rid=i, prompt=np.arange(3 + 4 * i, dtype=np.int32),
+    reqs = [Request(rid=i, prompt=np.arange(3 + 8 * i, dtype=np.int32),
                     max_new_tokens=5) for i in range(3)]
-    reqs[1].logits = []
+    reqs[1].logits, reqs[2].logits = [], []
     for r in reqs:
         eng.add_request(r)
     eng.run()
-    r = reqs[1]
-    toks = np.concatenate([r.prompt, np.asarray(r.out[:-1], np.int32)])
-    logits, _ = api.forward(params, {"tokens": jnp.asarray([toks])}, cfg, pol)
-    want = np.asarray(logits[0, len(r.prompt) - 1:])
-    np.testing.assert_allclose(np.stack(r.logits), want, atol=1e-4)
+    for r in reqs[1:]:
+        toks = np.concatenate([r.prompt, np.asarray(r.out[:-1], np.int32)])
+        logits, _ = api.forward(params, {"tokens": jnp.asarray([toks])}, cfg,
+                                pol)
+        want = np.asarray(logits[0, len(r.prompt) - 1:])
+        np.testing.assert_allclose(np.stack(r.logits), want, atol=1e-4)
     assert reqs[0].logits is None
 
 
